@@ -36,11 +36,10 @@ Each row also carries (since schema_version 2):
     XLA cost analysis with its measured warm p50 (captured by the obs
     layer at the run's own compiles, zero extra compiles); ``probes``
     AOT-times the per-iteration kernel at the row's shapes.
-  * ``device_peak_bytes`` — the backend allocator's peak when it
-    reports one (TPU/GPU ``memory_stats``), else the peak-RSS delta
-    over the bench's start (the CPU backend allocates from RSS);
-    ``device_peak_bytes_source`` says which.  The RSS delta is a
-    process-wide high-water mark, so later rows upper-bound earlier
+  * ``device_peak_bytes`` — the TPU allocator's peak
+    (``memory_stats()["peak_bytes_in_use"]``), ``None`` off the TPU;
+    ``peak_rss_bytes`` is the host process's peak RSS.  Both are
+    process-wide high-water marks, so later rows upper-bound earlier
     peaks rather than resetting per row.
 
 The kmeans family sweeps to C=16k flat, then rides the two-level
@@ -135,29 +134,21 @@ def edge_build_seconds(c: int, sketch_dim: int, edges: str, knn_k: int,
     return float(np.median(times))
 
 
-def _peak_bytes(rss_baseline: int) -> dict:
-    """Device allocator peak when the backend reports it (TPU/GPU), else
-    the peak-RSS delta over the bench baseline; the source is recorded
-    so consumers know which estimate they are reading."""
-    stats = {}
-    try:
-        stats = jax.local_devices()[0].memory_stats() or {}
-    except Exception:  # noqa: BLE001 - CPU backends may not implement it
-        pass
-    peak_rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-    dev = stats.get("peak_bytes_in_use")
-    source = "memory_stats"
-    if dev is None:
-        dev = max(peak_rss - rss_baseline, 0)
-        source = "rss_delta"
-    return {"device_peak_bytes": int(dev),
-            "device_peak_bytes_source": source,
-            "peak_rss_bytes": peak_rss}
+def _peak_bytes() -> dict:
+    """The device allocator's peak on the TPU, where ``memory_stats()``
+    must answer; ``None`` elsewhere.  The process's peak RSS is recorded
+    beside it under its own name."""
+    dev = jax.local_devices()[0]
+    peak = None
+    if dev.platform == "tpu":
+        peak = int(dev.memory_stats()["peak_bytes_in_use"])
+    return {"device_peak_bytes": peak,
+            "peak_rss_bytes":
+                resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}
 
 
 def run(sweeps=SWEEPS, out: str = OUT):
-    hw = detect_hardware()
-    rss_baseline = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    hw = detect_hardware() if jax.devices()[0].platform == "tpu" else None
     rows = []
     for algorithm, c_grid, overrides in sweeps:
         tag = algorithm
@@ -183,7 +174,7 @@ def run(sweeps=SWEEPS, out: str = OUT):
                 edge_build_s = edge_build_seconds(
                     c, summary["sketch_dim"], summary["edges"],
                     summary.get("knn_k") or 8)
-            row = {**summary, **serving, **_peak_bytes(rss_baseline),
+            row = {**summary, **serving, **_peak_bytes(),
                    "edge_build_s": edge_build_s,
                    "kernels": {
                        "programs": program_rows_from_snapshot(snap, hw),

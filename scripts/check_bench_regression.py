@@ -53,7 +53,7 @@ SERVING_SCHEMA_VERSION = 1
 
 ENGINE_ROW_KEYS = {
     "clients", "algorithm", "phases", "purity", "n_clusters_recovered",
-    "comm_bytes", "device_peak_bytes", "device_peak_bytes_source",
+    "comm_bytes", "device_peak_bytes", "peak_rss_bytes",
     "route_probes", "route_p50_ms", "route_p99_ms", "routes_per_s",
     "finalize_repeats", "finalize_p50_ms", "finalize_p99_ms", "kernels",
     # schema 3: mutable-serving columns (nullable on non-mutated rows)
@@ -115,11 +115,11 @@ def validate_engine(report: dict, failures: list) -> None:
                                         if missing else ""))
         if missing:
             continue
-        _check(failures, row["device_peak_bytes"] is not None
-               and row["device_peak_bytes"] > 0,
-               f"engine row {i} device_peak_bytes non-null "
-               f"({row['device_peak_bytes']}, "
-               f"source={row.get('device_peak_bytes_source')})")
+        if report.get("backend") == "tpu":
+            _check(failures, row["device_peak_bytes"] is not None
+                   and row["device_peak_bytes"] > 0,
+                   f"engine row {i} device_peak_bytes non-null on the TPU "
+                   f"({row['device_peak_bytes']})")
     _validate_hierarchical(rows, failures)
     _validate_knn_approx(rows, failures)
 
@@ -265,7 +265,7 @@ def quick_check(baseline: dict, failures: list) -> None:
         _check(failures, row["phases"][phase] <= cap,
                f"{phase} {row['phases'][phase]:.2f}s <= {cap:.2f}s "
                f"(baseline {base['phases'][phase]:.2f}s)")
-    if base.get("device_peak_bytes"):
+    if base.get("device_peak_bytes") and row["device_peak_bytes"]:
         cap = base["device_peak_bytes"] * MEM_MULT + MEM_SLACK_B
         _check(failures, row["device_peak_bytes"] <= cap,
                f"device_peak_bytes {row['device_peak_bytes']} <= {cap:.0f}")

@@ -48,6 +48,7 @@ from repro.core.clustering.api import (
 from repro.core.engine.aggregators import (
     cluster_aggregate_tree,
     get_aggregator,
+    matmul_f32,
 )
 from repro.core.federated import FederatedState, _router_invariant_filter
 from repro.core.sketch import sketch_tree
@@ -94,13 +95,20 @@ def _cluster_and_average(algo, options, k, constrain, cluster_key,
     return new_params, res
 
 
+# how a compiled Pallas kernel appears in the HLO of a TPU program
+_PALLAS_CALL = 'custom_call_target="tpu_custom_call"'
+
+
 class _Program:
     """AOT-compiled program with compile-vs-execute telemetry.
 
     Wraps a traceable function: the first call per input-shape
     signature runs ``jit(fn).lower(*args).compile()`` under a
     ``"<label>.compile"`` span and records the compiled module's XLA
-    cost analysis as ``"<label>.flops"`` / ``"<label>.bytes"`` gauges;
+    cost analysis as ``"<label>.flops"`` / ``"<label>.bytes"`` gauges
+    and its number of compiled Pallas kernels as
+    ``"<label>.pallas_kernels"`` (0 off the TPU, where the kernels
+    dispatch to their jnp oracles);
     every call then executes (blocking to completion) under a
     ``"<label>.execute"`` span.  This is what splits the historically
     conflated "first round is slow" wall clock into trace/compile vs
@@ -126,11 +134,11 @@ class _Program:
             with obs.span(f"{self.label}.compile"):
                 compiled = jax.jit(self._fn).lower(*args).compile()
             cost = compiled.cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):   # older jax: per-device list
-                cost = cost[0] if cost else {}
             obs.gauge(f"{self.label}.flops", float(cost.get("flops", 0.0)))
             obs.gauge(f"{self.label}.bytes",
                       float(cost.get("bytes accessed", 0.0)))
+            obs.gauge(f"{self.label}.pallas_kernels",
+                      float(compiled.as_text().count(_PALLAS_CALL)))
             self._cache[sig] = compiled
         with obs.span(f"{self.label}.execute"):
             out = compiled(*args)
@@ -234,9 +242,9 @@ def _weighted_mean_program(mesh, client_axis):
 
         def back(leaf):
             flat = leaf.reshape(leaf.shape[0], -1).astype(jnp.float32)
-            means = (weighted.T @ flat) / denom                    # (K, n)
-            return constrain(
-                (onehot @ means).reshape(leaf.shape).astype(leaf.dtype))
+            means = matmul_f32(weighted.T, flat) / denom           # (K, n)
+            return constrain(matmul_f32(onehot, means).reshape(
+                leaf.shape).astype(leaf.dtype))
 
         return jax.tree_util.tree_map(back, params)
 

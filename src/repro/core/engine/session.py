@@ -273,6 +273,23 @@ class AggregationSession:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, P(self.client_axis)))
 
+    def _to_cluster_device(self, sketches):
+        """Under a mesh, the clustering and route programs run on the
+        mesh's first device: they hold Pallas kernels, which XLA cannot
+        partition across devices.  What moves there is a (rows,
+        sketch_dim) matrix, small next to the sharded parameter
+        buffer."""
+        if self.mesh is None:
+            return sketches
+        return jax.device_put(sketches, self.mesh.devices.flat[0])
+
+    def _replicate(self, x):
+        """Under a mesh, copy a clustering output to every device so the
+        sharded parameter reduction can read it."""
+        if self.mesh is None:
+            return x
+        return jax.device_put(x, NamedSharding(self.mesh, P()))
+
     @property
     def count(self) -> int:
         """Live clients currently held (re-uploads replace, evictions
@@ -699,23 +716,24 @@ class AggregationSession:
         """The finalize's parameter-averaging phase: the shared
         unweighted mean program (bit-exact with the fused round) unless
         the staleness policy supplies decay weights."""
+        labels, centers = self._replicate((res.labels, res.centers))
         if weights is None:
             return cached_program(_mean_program, self.mesh,
                                   self.client_axis,
                                   get_aggregator(aggregator))(
-                res.labels, res.centers, params)
+                labels, centers, params)
         if get_aggregator(aggregator).name != "mean":
             raise ValueError(
                 "staleness weighting (exp_decay) requires the 'mean' "
                 f"aggregator, got {get_aggregator(aggregator).name!r}")
         return cached_program(_weighted_mean_program, self.mesh,
                               self.client_axis)(
-            res.labels, res.centers, params,
-            jnp.asarray(weights, jnp.float32))
+            labels, centers, params,
+            self._replicate(jnp.asarray(weights, jnp.float32)))
 
     def _finalize_device(self, algo, k, algo_options, snap, aggregator,
                          warm):
-        sketches, params = snap.sketches, snap.params
+        sketches, params = self._to_cluster_device(snap.sketches), snap.params
         cluster_key = jax.random.PRNGKey(self.cluster_seed)
         opts = tuple(sorted((algo_options or {}).items()))
         # the cluster and mean phases run as two AOT programs (labels /
@@ -843,7 +861,7 @@ class AggregationSession:
             sketch = self._sketch_one(params)
         sketch = jnp.asarray(sketch, jnp.float32)
         single = sketch.ndim == 1
-        pts = sketch[None] if single else sketch
+        pts = self._to_cluster_device(sketch[None] if single else sketch)
         n = int(pts.shape[0])
         if n == 0:
             # tracing a zero-row assign program would succeed and cache

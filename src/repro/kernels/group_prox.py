@@ -3,20 +3,24 @@
 The AMA solver for convex clustering (repro.core.clustering.convex and
 its device twin repro.core.engine.device_convex) projects every edge's
 dual variable onto the ball of radius lambda each iteration: for
-E = m(m-1)/2 edges and sketch dim d this is an (E, d)
-row-normalization — memory bound, so we tile rows through VMEM in
-(be, d) blocks and fuse the norm + rescale.
+E edges and sketch dim d this is an (E, d) row-normalization — memory
+bound, so we tile rows through VMEM in (be, d) blocks and fuse the norm
+and the rescale.
 
-  grid = (E/be,)
-  V tile: (be, d) VMEM    radius tile: (be,)    out: (be, d)
+The kernel runs over a leading batch axis — the lambda-ladder sweep of
+the device clusterpath advances all L solves in lock-step, so its dual
+state is (L, E, d) with a per-(l, e) radius; the unbatched projection
+is the L=1 case.  E is padded to a multiple of ``be`` (pad radius 1.0
+=> pad rows pass through unscaled and are sliced off).
 
-The batched variant below runs the same projection over a leading batch
-axis — the lambda-ladder sweep of the device clusterpath advances all L
-solves in lock-step, so its dual state is (L, E, d) with a per-(l, e)
-radius.  The grid grows a batch dimension; edge tiles keep the same
-(be, d) VMEM footprint and E is padded to a multiple of ``be`` exactly
-as in the unbatched kernel (pad radius 1.0 => pad rows pass through
-unscaled and are sliced off).
+The radius travels as a lane-dense (L, 1, E) array in (1, 1, be)
+blocks and is turned into a (be, 1) column inside the kernel: a 1-D
+(be,) or (1, be) radius block does not match the TPU's HBM tiling and
+Mosaic refuses it, and an (E, 1) column would pad every radius to a
+full 128-lane row in HBM.
+
+  grid = (L, E/be)
+  V tile: (1, be, d)    radius tile: (1, 1, be)    out: (1, be, d)
 """
 from __future__ import annotations
 
@@ -28,49 +32,30 @@ from jax.experimental import pallas as pl
 
 
 def _proj_kernel(v_ref, r_ref, o_ref):
-    v = v_ref[...].astype(jnp.float32)                    # (be, d)
-    r = r_ref[...].astype(jnp.float32)                    # (be,)
-    n = jnp.sqrt(jnp.sum(v * v, axis=1))                  # (be,)
+    v = v_ref[0].astype(jnp.float32)                      # (be, d)
+    r = r_ref[0].astype(jnp.float32).T                    # (be, 1)
+    n = jnp.sqrt(jnp.sum(v * v, axis=1, keepdims=True))   # (be, 1)
     scale = jnp.where(n > r, r / jnp.maximum(n, 1e-30), 1.0)
-    o_ref[...] = v * scale[:, None]
+    o_ref[0] = v * scale
 
 
 @functools.partial(jax.jit, static_argnames=("be", "interpret"))
-def group_ball_proj_pallas(v, radius, *, be: int = 512, interpret: bool = False):
-    e, d = v.shape
-    if e == 0:          # degenerate edge set (m=1): nothing to project
-        return jnp.zeros((0, d), jnp.float32)
+def group_ball_proj_pallas(v, radius, *, be: int = 512,
+                           interpret: bool = False):
+    """Row-wise ball projection: v (e, d), radius scalar or (e,)."""
+    e = v.shape[0]
     radius = jnp.broadcast_to(jnp.asarray(radius, jnp.float32), (e,))
-    be = min(be, _rup(e, 8))
-    ep = _rup(e, be)
-    vp = jnp.pad(v, ((0, ep - e), (0, 0)))
-    rp = jnp.pad(radius, (0, ep - e), constant_values=1.0)
-    out = pl.pallas_call(
-        _proj_kernel,
-        grid=(ep // be,),
-        in_specs=[
-            pl.BlockSpec((be, d), lambda i: (i, 0)),
-            pl.BlockSpec((be,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((be, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ep, d), jnp.float32),
-        interpret=interpret,
-    )(vp, rp)
-    return out[:e]
-
-
-def _batched_proj_kernel(v_ref, r_ref, o_ref):
-    v = v_ref[0].astype(jnp.float32)                      # (be, d)
-    r = r_ref[0].astype(jnp.float32)                      # (be,)
-    n = jnp.sqrt(jnp.sum(v * v, axis=1))                  # (be,)
-    scale = jnp.where(n > r, r / jnp.maximum(n, 1e-30), 1.0)
-    o_ref[0] = v * scale[:, None]
+    return group_ball_proj_batched_pallas(v[None], radius[None], be=be,
+                                          interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("be", "interpret"))
 def group_ball_proj_batched_pallas(v, radius, *, be: int = 512,
                                    interpret: bool = False):
-    """Batched row-wise ball projection: v (b, e, d), radius (b, e)."""
+    """Batched row-wise ball projection: v (b, e, d), radius (b, e).
+
+    On TPU ``be`` must be a multiple of 128 unless one block spans all
+    of ``e``."""
     b, e, d = v.shape
     if e == 0:          # degenerate edge set (m=1): nothing to project
         return jnp.zeros((b, 0, d), jnp.float32)
@@ -78,13 +63,14 @@ def group_ball_proj_batched_pallas(v, radius, *, be: int = 512,
     be = min(be, _rup(e, 8))
     ep = _rup(e, be)
     vp = jnp.pad(v, ((0, 0), (0, ep - e), (0, 0)))
-    rp = jnp.pad(radius, ((0, 0), (0, ep - e)), constant_values=1.0)
+    rp = jnp.pad(radius, ((0, 0), (0, ep - e)),
+                 constant_values=1.0)[:, None, :]         # (b, 1, ep)
     out = pl.pallas_call(
-        _batched_proj_kernel,
+        _proj_kernel,
         grid=(b, ep // be),
         in_specs=[
             pl.BlockSpec((1, be, d), lambda l, i: (l, i, 0)),
-            pl.BlockSpec((1, be), lambda l, i: (l, i)),
+            pl.BlockSpec((1, 1, be), lambda l, i: (l, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, be, d), lambda l, i: (l, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, ep, d), jnp.float32),

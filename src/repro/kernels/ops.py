@@ -1,10 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the compiled Pallas kernels run natively; everywhere else (this
-container is CPU-only) the wrappers dispatch to the pure-jnp oracles in
-``ref.py`` so the rest of the framework is backend-agnostic.  Tests call
-the ``*_pallas(..., interpret=True)`` entry points directly to validate
-the kernel bodies against the oracles.
+On TPU the compiled Pallas kernels run, with no fallback; on other
+backends the wrappers dispatch to the pure-jnp oracles in ``ref.py`` so
+the rest of the framework is backend-agnostic.  Tests call the
+``*_pallas(..., interpret=True)`` entry points directly to validate the
+kernel bodies against the oracles, and ``tests/test_tpu_compile.py``
+compiles them for a described TPU.
 """
 from __future__ import annotations
 

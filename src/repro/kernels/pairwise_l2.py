@@ -14,7 +14,9 @@ sketch dims stream through VMEM:
   A tile: (bm, bd) VMEM     B tile: (bk, bd) VMEM     O tile: (bm, bk)
 
 All tile sizes are MXU-aligned multiples of 128 (8 for the sublane dim
-would suffice for fp32 but 128 keeps the matmul shapes square).
+would suffice for fp32 but 128 keeps the matmul shapes square).  The
+matmul runs at HIGHEST precision: float32 on the MXU, as the docstring
+of ``pairwise_sqdist_pallas`` promises.
 """
 from __future__ import annotations
 
@@ -35,7 +37,8 @@ def _pairwise_kernel(a_ref, b_ref, o_ref):
     a2 = jnp.sum(a * a, axis=1, keepdims=True)  # (bm, 1)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)  # (bk, 1)
     ab = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32
     )                                           # (bm, bk)
     o_ref[...] += a2 + b2.T - 2.0 * ab
 

@@ -185,6 +185,7 @@ def main(argv=None):
                     help="write every obs span/event (routing, finalize) "
                          "of this serve run as JSONL")
     args = ap.parse_args(argv)
+    runtime.use_compile_cache()
     if args.trace:
         obs.add_sink(obs.JsonlSink(args.trace))
 

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import obs, runtime
 from repro.core.clustering import (
     device_twin,
     get_algorithm,
@@ -605,6 +605,7 @@ def main(argv=None):
                     help="seconds per QPS measurement loop")
     ap.add_argument("--out", default=None, help="write the summary JSON here")
     args = ap.parse_args(argv)
+    runtime.use_compile_cache()
 
     # flat option superset -> per-scenario dataclass fields, filtered by
     # build_scenario exactly like build_federated_method filters methods

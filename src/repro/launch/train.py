@@ -38,7 +38,7 @@ import time
 import jax
 import numpy as np
 
-from repro import obs
+from repro import obs, runtime
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_config
 from repro.core.federated import evaluate_per_client, init_federation
@@ -110,6 +110,7 @@ def main(argv=None):
                     help="write every obs span/event (engine round, "
                          "per-round comm) of this run as JSONL")
     args = ap.parse_args(argv)
+    runtime.use_compile_cache()
     if args.trace:
         obs.add_sink(obs.JsonlSink(args.trace))
 

@@ -8,7 +8,7 @@ from repro.roofline.analysis import (
     roofline_terms,
 )
 from repro.roofline.engine_costs import (
-    HW_CPU,
+    PEAKS,
     achieved_vs_peak,
     detect_hardware,
     engine_kernel_report,
@@ -18,8 +18,8 @@ from repro.roofline.engine_costs import (
 )
 
 __all__ = [
-    "HW_CPU",
     "HW_V5E",
+    "PEAKS",
     "Hardware",
     "RooflineReport",
     "achieved_vs_peak",

@@ -28,6 +28,9 @@ class Hardware:
     link_bw: float           # bytes/s per ICI link
 
 
+# One TPU v5e chip, from Google Cloud documentation, "TPU v5e" page:
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI over
+# four links (50 GB/s each).
 HW_V5E = Hardware(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
 
 _DTYPE_BYTES = {

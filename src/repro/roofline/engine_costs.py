@@ -17,12 +17,10 @@ with its *measured* execute time:
     compile+time of the per-iteration kernels at a given problem size,
     for the bench rows' ``kernels`` section.
 
-Peaks come from the shared ``Hardware`` dataclass.  On TPU the real
-v5e numbers apply; elsewhere ``HW_CPU`` is a *nominal* reference chip
-(order-of-magnitude laptop-class peaks) so the fraction-of-peak columns
-stay comparable across bench runs on the same backend — they are NOT a
-claim about the actual host silicon, and ``hw["name"]`` in every report
-says which reference was used.
+Peaks come from ``PEAKS``, keyed by the ``device_kind`` jax reports.
+A device missing from that table is an error, not a default: a CPU run
+has no peak to be a fraction of, so callers pass ``hw=None`` there and
+the rows carry no ``*_frac_of_peak`` columns.
 """
 from __future__ import annotations
 
@@ -33,50 +31,53 @@ import jax.numpy as jnp
 
 from repro.roofline.analysis import HW_V5E, Hardware
 
-# nominal laptop-class reference peaks for non-TPU backends: ~100 GFLOP/s
-# f32, ~25 GB/s memory, ~10 GB/s interconnect.  Deliberately round
-# numbers — the point is stable achieved/peak ratios across runs, not
-# host-silicon accuracy.
-HW_CPU = Hardware(name="cpu-nominal", peak_flops=1e11, hbm_bw=2.5e10,
-                  link_bw=1e10)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#   "TPU v5 lite": Google Cloud documentation, "TPU v5e" page — 197
+#   TFLOP/s bf16, 16 GB HBM at 819 GB/s (``analysis.HW_V5E``).
+PEAKS = {"TPU v5 lite": HW_V5E}
 
 
-def detect_hardware(backend: str | None = None) -> Hardware:
-    """The reference Hardware for the active (or given) jax backend."""
-    b = backend or jax.default_backend()
-    return HW_V5E if b == "tpu" else HW_CPU
+def detect_hardware(device=None) -> Hardware:
+    """Published peaks of ``device`` (default: the first jax device).
+    Raises for a device kind missing from ``PEAKS``."""
+    dev = device if device is not None else jax.devices()[0]
+    hw = PEAKS.get(dev.device_kind)
+    if hw is None:
+        raise ValueError(
+            f"no published peaks for device kind {dev.device_kind!r} "
+            f"(platform {dev.platform!r}); add them to "
+            "roofline.engine_costs.PEAKS with their source")
+    return hw
 
 
-def _cost_dict(compiled) -> dict:
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):    # older jax: per-device list
-        cost = cost[0] if cost else {}
-    return cost
-
-
-def achieved_vs_peak(cost: dict, seconds: float, hw: Hardware) -> dict:
+def achieved_vs_peak(cost: dict, seconds: float,
+                     hw: Hardware | None) -> dict:
     """One program's roofline row: cost_analysis dict + measured wall
-    seconds -> achieved rates and fraction-of-peak."""
+    seconds -> achieved rates, and fraction-of-peak when ``hw`` has
+    published peaks (``None`` off the TPU)."""
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     s = max(float(seconds), 1e-12)
-    return {
+    row = {
         "flops": flops,
         "bytes": nbytes,
         "exec_s": float(seconds),
         "achieved_flops_per_s": flops / s,
         "achieved_bytes_per_s": nbytes / s,
-        "flops_frac_of_peak": flops / s / hw.peak_flops,
-        "bytes_frac_of_peak": nbytes / s / hw.hbm_bw,
     }
+    if hw is not None:
+        row["flops_frac_of_peak"] = flops / s / hw.peak_flops
+        row["bytes_frac_of_peak"] = nbytes / s / hw.hbm_bw
+    return row
 
 
-def kernel_probe(name: str, fn, args, hw: Hardware, iters: int = 3) -> dict:
+def kernel_probe(name: str, fn, args, hw: Hardware | None,
+                 iters: int = 3) -> dict:
     """AOT-compile ``fn`` at the shapes of ``args`` and time warm
     executions; returns an achieved-vs-peak row tagged with the arg
     shapes."""
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     jax.block_until_ready(compiled(*args))            # warmup
     t0 = time.perf_counter()
     for _ in range(iters):
@@ -92,7 +93,7 @@ def kernel_probe(name: str, fn, args, hw: Hardware, iters: int = 3) -> dict:
 def engine_kernel_report(clients: int, sketch_dim: int, k: int,
                          algorithm: str, *, edges: str = "complete",
                          knn_k: int = 8, max_edges: int = 1 << 21,
-                         hw: Hardware | None = None) -> list[dict]:
+                         hw: Hardware | None) -> list[dict]:
     """Probe the per-iteration kernel(s) a bench row's algorithm drives.
 
     Lloyd-family rows probe ``kmeans_assign`` at the row's (C, s) x
@@ -103,7 +104,6 @@ def engine_kernel_report(clients: int, sketch_dim: int, k: int,
     """
     from repro.kernels import ops as kops
 
-    hw = hw or detect_hardware()
     key = jax.random.PRNGKey(0)
     rows = []
     if algorithm.startswith("kmeans"):
@@ -127,7 +127,7 @@ def engine_kernel_report(clients: int, sketch_dim: int, k: int,
 
 
 def program_rows_from_snapshot(snapshot: dict,
-                               hw: Hardware | None = None) -> dict:
+                               hw: Hardware | None) -> dict:
     """Achieved-vs-peak per AOT program, from an ``obs.snapshot()``.
 
     Pairs every ``"<label>.flops"`` gauge with the matching
@@ -135,7 +135,6 @@ def program_rows_from_snapshot(snapshot: dict,
     — the programs the run actually compiled and ran, at their real
     shapes, with zero extra compiles.
     """
-    hw = hw or detect_hardware()
     gauges = snapshot.get("gauges", {})
     hists = snapshot.get("histograms", {})
     out = {}
@@ -154,8 +153,12 @@ def program_rows_from_snapshot(snapshot: dict,
     return out
 
 
-def hardware_info(hw: Hardware | None = None) -> dict:
-    hw = hw or detect_hardware()
-    return {"name": hw.name, "peak_flops": hw.peak_flops,
-            "hbm_bw": hw.hbm_bw, "link_bw": hw.link_bw,
-            "backend": jax.default_backend()}
+def hardware_info(hw: Hardware | None) -> dict:
+    """The device a report ran on, as jax names it, plus its peaks
+    (``None`` where the device has none in ``PEAKS``)."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices()),
+            "peaks": None if hw is None else {
+                "name": hw.name, "peak_flops": hw.peak_flops,
+                "hbm_bw": hw.hbm_bw, "link_bw": hw.link_bw}}

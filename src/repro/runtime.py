@@ -26,6 +26,9 @@ Environment overrides read by :func:`apply_env_presets`:
 ``REPRO_CPU_THREADS``  — pin host thread pools (OMP/MKL/Eigen) to N
 ``REPRO_HOST_DEVICES`` — fake N host devices (mesh tests on CPU)
 ``REPRO_XLA_FLAGS``    — extra raw XLA flags, merged (last wins)
+
+:func:`use_compile_cache` places JAX's persistent compilation cache
+(call it from an entry point's ``main``, before the first compile).
 """
 from __future__ import annotations
 
@@ -34,6 +37,10 @@ import sys
 import warnings
 
 _TRUTHY = {"1", "true", "yes", "on"}
+
+# the checkout root (src/repro/runtime.py -> ../..)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def jax_imported() -> bool:
@@ -147,3 +154,21 @@ def apply_env_presets() -> dict:
         add_xla_flags(extra)
         applied["xla_flags"] = extra
     return applied
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``: the directory is part of what a cached
+    entry is found by, so a temporary or per-process path would never
+    be hit again."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

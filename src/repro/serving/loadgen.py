@@ -417,6 +417,7 @@ def main(argv=None) -> int:
                          "below ~4)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    runtime.use_compile_cache()
     callers = tuple(int(c) for c in str(args.callers).split(",") if c)
     report = run(clients=args.clients, clusters=args.clusters,
                  sketch_dim=args.sketch_dim, callers=callers,

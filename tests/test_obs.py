@@ -170,3 +170,24 @@ def test_merge_order_independent_property():
                 assert ha[f] == pytest.approx(hb[f], abs=1e-9)
 
     check()
+
+
+def test_detect_hardware_reads_peaks_by_device_kind():
+    """Peaks come from the published table keyed by ``device_kind``; a
+    kind missing from it (the CPU included) is an error, and rows made
+    without peaks carry no fraction-of-peak columns."""
+    from types import SimpleNamespace
+
+    from repro.roofline import HW_V5E, achieved_vs_peak, detect_hardware
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert detect_hardware(v5e) is HW_V5E
+    for kind in ("TPU v99", "cpu"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            detect_hardware(SimpleNamespace(platform="tpu", device_kind=kind))
+    with pytest.raises(ValueError):
+        detect_hardware()                # this process runs on the CPU
+    cost = {"flops": 2e9, "bytes accessed": 1e9}
+    assert "flops_frac_of_peak" not in achieved_vs_peak(cost, 1.0, None)
+    row = achieved_vs_peak(cost, 1.0, HW_V5E)
+    assert row["bytes_frac_of_peak"] == pytest.approx(1e9 / HW_V5E.hbm_bw)

@@ -110,3 +110,25 @@ def test_runtime_module_does_not_import_jax():
                                "PYTHONPATH": os.pathsep.join(sys.path)},
                           capture_output=True)
     assert proc.returncode == 0, proc.stderr.decode()
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_use_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    otherwise the cache sits at the fixed ``<checkout>/.jax_cache``."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want, config_after = str(tmp_path), before
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = config_after = os.path.join(checkout, ".jax_cache")
+    try:
+        assert runtime.use_compile_cache() == want
+        assert runtime.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == config_after
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

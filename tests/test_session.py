@@ -132,6 +132,28 @@ def test_route_self_consistency_and_cluster_model():
                                   np.asarray(new_state.params["theta"][0]))
 
 
+def test_mesh_session_matches_single_device_session():
+    """The sharded client axis (``mesh=``) serves the same round as a
+    session without a mesh: buffers shard over the mesh, clustering and
+    routing run on its first device."""
+    from jax.sharding import Mesh
+
+    pts, _ = make_blobs(6, [9, 8, 7], 8)
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+    plain = ingest_in_waves(AggregationSession(len(pts), sketch_dim=16),
+                            pts, [5, 9])
+    sharded = ingest_in_waves(
+        AggregationSession(len(pts), sketch_dim=16, mesh=mesh), pts, [5, 9])
+    assert sharded._params["theta"].sharding.spec == ("data",)
+    want = plain.finalize(algorithm="kmeans-device", k=3)
+    got = sharded.finalize(algorithm="kmeans-device", k=3)
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_allclose(np.asarray(got[0].params["theta"]),
+                               np.asarray(want[0].params["theta"]),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(sharded.route(sharded.sketches), want[1])
+
+
 def test_route_unseen_client_goes_to_nearest_cluster():
     pts, true = make_blobs(5, [10, 10], 6, sep=30.0, noise=0.2)
     # hold out the last client of each cluster
