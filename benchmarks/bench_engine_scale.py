@@ -32,10 +32,8 @@ Each row also carries (since schema_version 2):
     exercise runs OUTSIDE the phase timings, so ``total_s`` stays
     comparable with schema-1 rows.
   * ``kernels`` — achieved-vs-peak roofline rows
-    (``roofline.engine_costs``): ``programs`` pairs each AOT program's
-    XLA cost analysis with its measured warm p50 (captured by the obs
-    layer at the run's own compiles, zero extra compiles); ``probes``
-    AOT-times the per-iteration kernel at the row's shapes.
+    (``roofline.engine_costs``): ``probes`` AOT-times the
+    per-iteration kernel at the row's shapes.
   * ``device_peak_bytes`` — the TPU allocator's peak
     (``memory_stats()["peak_bytes_in_use"]``), ``None`` off the TPU;
     ``peak_rss_bytes`` is the host process's peak RSS.  Both are
@@ -75,7 +73,6 @@ from repro.roofline.engine_costs import (
     detect_hardware,
     engine_kernel_report,
     hardware_info,
-    program_rows_from_snapshot,
 )
 
 CLUSTERS = 8
@@ -160,7 +157,7 @@ def run(sweeps=SWEEPS, out: str = OUT):
             summary = simulate(clients=c, clusters=CLUSTERS,
                                algorithm=algorithm,
                                **{"wave": 4096, **overrides})
-            snap = summary.pop("obs")
+            summary.pop("obs")
             serving = summary.pop("serving") or {}
             # hierarchical rows probe at the per-shard level-0 shapes —
             # that is the program the round actually compiles
@@ -176,9 +173,7 @@ def run(sweeps=SWEEPS, out: str = OUT):
                     summary.get("knn_k") or 8)
             row = {**summary, **serving, **_peak_bytes(),
                    "edge_build_s": edge_build_s,
-                   "kernels": {
-                       "programs": program_rows_from_snapshot(snap, hw),
-                       "probes": probes}}
+                   "kernels": {"probes": probes}}
             rows.append(row)
             ph = summary["phases"]
             emit(f"bench_engine/{tag}/C{c}", ph["aggregate_s"] * 1e6,

@@ -20,8 +20,7 @@ sketch matrix it accumulated wave-by-wave — the paths stay bit-exact
 because both trace the identical ``device_call`` /
 ``_average_clusters`` bodies (pinned by ``tests/test_session.py``).
 Every program here is a ``_Program``: AOT ``lower().compile()`` per
-input shape with compile-vs-execute spans and XLA cost-analysis
-(flops / bytes) gauges recorded to ``repro.obs``.
+input shape with compile-vs-execute spans recorded to ``repro.obs``.
 
 Under a mesh the client axis shards over ``data`` (the same stacked
 layout as ``federated.py``): the label/center reductions inside the
@@ -104,17 +103,13 @@ class _Program:
 
     Wraps a traceable function: the first call per input-shape
     signature runs ``jit(fn).lower(*args).compile()`` under a
-    ``"<label>.compile"`` span and records the compiled module's XLA
-    cost analysis as ``"<label>.flops"`` / ``"<label>.bytes"`` gauges
-    and its number of compiled Pallas kernels as
-    ``"<label>.pallas_kernels"`` (0 off the TPU, where the kernels
-    dispatch to their jnp oracles);
-    every call then executes (blocking to completion) under a
+    ``"<label>.compile"`` span and records the compiled module's number
+    of compiled Pallas kernels as the ``"<label>.pallas_kernels"``
+    gauge (0 off the TPU, where the kernels dispatch to their jnp
+    oracles); every call then executes (blocking to completion) under a
     ``"<label>.execute"`` span.  This is what splits the historically
     conflated "first round is slow" wall clock into trace/compile vs
-    execute in the bench rows, and what feeds
-    ``roofline.engine_costs`` its achieved-vs-peak numbers without a
-    second compile of the round.
+    execute.
     """
 
     def __init__(self, label: str, fn):
@@ -133,10 +128,6 @@ class _Program:
         if compiled is None:
             with obs.span(f"{self.label}.compile"):
                 compiled = jax.jit(self._fn).lower(*args).compile()
-            cost = compiled.cost_analysis() or {}
-            obs.gauge(f"{self.label}.flops", float(cost.get("flops", 0.0)))
-            obs.gauge(f"{self.label}.bytes",
-                      float(cost.get("bytes accessed", 0.0)))
             obs.gauge(f"{self.label}.pallas_kernels",
                       float(compiled.as_text().count(_PALLAS_CALL)))
             self._cache[sig] = compiled
@@ -156,7 +147,7 @@ def _round_program(algo, k, opts, sketch_dim, leaf_filter, mesh, client_axis,
     parity tests, multi-round drivers) reuse the compiled program
     instead of retracing a fresh closure every call.  Returns a
     ``_Program`` — AOT-compiled per shape with compile/execute spans
-    and roofline counters under the ``"engine.round"`` label.
+    under the ``"engine.round"`` label.
     """
     options = dict(opts)
     constrain = _constrainer(mesh, client_axis)

@@ -273,6 +273,15 @@ class AggregationSession:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, P(self.client_axis)))
 
+    def _upload(self, tree):
+        """Start the host-to-device transfer of an upload (wave leaves,
+        row index): to the default device, uncommitted, where the jitted
+        ingest takes host arrays; under a mesh replicated over it, which
+        is the input sharding the compiled ingest reads, so no reshard
+        follows the transfer."""
+        target = None if self.mesh is None else NamedSharding(self.mesh, P())
+        return jax.device_put(tree, target)
+
     def _to_cluster_device(self, sketches):
         """Under a mesh, the clustering and route programs run on the
         mesh's first device: they hold Pallas kernels, which XLA cannot
@@ -398,11 +407,6 @@ class AggregationSession:
         self._stamps[rows] = self._clock
         self._final = None             # buffer contents left the round
         self.evict_stale()
-        self._gauge_slots()
-
-    def _gauge_slots(self) -> None:
-        obs.gauge("session.slots.live", float(self._count))
-        obs.gauge("session.slots.free", float(self.capacity - self._count))
 
     @staticmethod
     def _contiguous(rows: np.ndarray) -> bool:
@@ -448,18 +452,24 @@ class AggregationSession:
                     jnp.zeros((self.capacity,) + l.shape[1:], l.dtype)),
                 wave)
         offset = int(rows[0])
+        contiguous = self._contiguous(rows)
         with obs.span("session.ingest", wave=w, offset=offset,
                       mode="params"):
-            if self._contiguous(rows):
-                self._sketches, self._params = self._ingest_fn(
-                    self._sketches, self._params, wave,
-                    jnp.asarray(offset, jnp.int32))
-            else:
-                self._sketches, self._params = self._ingest_scatter_fn(
-                    self._sketches, self._params, wave,
-                    jnp.asarray(rows, jnp.int32))
-            jax.block_until_ready(self._sketches)
-        obs.count("session.ingest.clients", w)
+            # the program is queued before the wave lands, as a jitted
+            # call on host arrays queues it, so it starts on the transfer's
+            # heels: the transfer span ends when the wave is on the
+            # device, the program span when the buffers are written
+            with obs.span("session.ingest.transfer"):
+                wave, index = self._upload(
+                    (wave, np.int32(offset) if contiguous
+                     else np.asarray(rows, np.int32)))
+                ingest_fn = (self._ingest_fn if contiguous
+                             else self._ingest_scatter_fn)
+                self._sketches, self._params = ingest_fn(
+                    self._sketches, self._params, wave, index)
+                jax.block_until_ready((wave, index))
+            with obs.span("session.ingest.program"):
+                jax.block_until_ready(self._sketches)
         obs.count("session.ingest.bytes",
                   sum(l.size * l.dtype.itemsize for l in leaves))
         self._commit_rows(rows, client_ids)
@@ -469,26 +479,29 @@ class AggregationSession:
         if self._mode == "params":
             raise ValueError("session already holds parameter waves; "
                              "cannot mix in sketch-only waves")
-        sketches = jnp.asarray(sketches, jnp.float32)
-        if sketches.ndim != 2 or sketches.shape[1] != self.sketch_dim:
+        shape = np.shape(sketches)
+        if len(shape) != 2 or shape[1] != self.sketch_dim:
             raise ValueError(f"sketch wave must be (w, {self.sketch_dim}), "
-                             f"got {sketches.shape}")
-        w = int(sketches.shape[0])
+                             f"got {shape}")
+        w = int(shape[0])
         if w < 1:
             raise ValueError("empty wave")
         rows, _ = self._alloc_rows(w, client_ids)
         self._mode = "sketches"    # only after validation, as above
         offset = int(rows[0])
+        contiguous = self._contiguous(rows)
         with obs.span("session.ingest", wave=w, offset=offset,
                       mode="sketches"):
-            if self._contiguous(rows):
-                self._sketches = self._ingest_sk_fn(
-                    self._sketches, sketches, jnp.asarray(offset, jnp.int32))
-            else:
-                self._sketches = self._ingest_sk_scatter_fn(
-                    self._sketches, sketches, jnp.asarray(rows, jnp.int32))
-            jax.block_until_ready(self._sketches)
-        obs.count("session.ingest.clients", w)
+            with obs.span("session.ingest.transfer"):      # as above
+                sketches = jnp.asarray(sketches, jnp.float32)
+                index = self._upload(np.int32(offset) if contiguous
+                                     else np.asarray(rows, np.int32))
+                ingest_fn = (self._ingest_sk_fn if contiguous
+                             else self._ingest_sk_scatter_fn)
+                self._sketches = ingest_fn(self._sketches, sketches, index)
+                jax.block_until_ready((sketches, index))
+            with obs.span("session.ingest.program"):
+                jax.block_until_ready(self._sketches)
         obs.count("session.ingest.bytes",
                   sketches.size * sketches.dtype.itemsize)
         self._commit_rows(rows, client_ids)
@@ -523,7 +536,6 @@ class AggregationSession:
         self._count -= len(out)
         self._final = None
         obs.count("session.evictions", len(out))
-        self._gauge_slots()
         return out
 
     def _live_weights(self, rows: np.ndarray):
@@ -749,31 +761,29 @@ class AggregationSession:
                 cluster_key, sketches)
             mode = "cold"
         self._cache_warm_state(algo, res, snap.count)
-        if params is None:
-            labels, uniq, first = compact_labels(res.labels)
-            info = {"n_clusters": int(len(uniq)),
-                    "meta": meta_to_host(res.meta),
-                    "engine": "device", "count": snap.count,
-                    "refinalize": mode if warm else None,
-                    "snapshot_clock": snap.clock}
-            out = (None, labels, info)
+        if params is not None:
+            new_params = self._average_params(res, params, aggregator,
+                                              snap.weights)
+        # the round's host tail: label compaction, meta pull, opt-state
+        # init and drift anchor, each sync waiting on queued device work
+        with obs.span("session.materialize"):
+            if params is None:
+                new_state = None
+                labels, uniq, first = compact_labels(res.labels)
+                info = {"n_clusters": int(len(uniq)),
+                        "meta": meta_to_host(res.meta), "engine": "device"}
+            else:
+                state = FederatedState(params=params, opt_state=None,
+                                       n_clients=snap.count, step=0)
+                new_state, labels, info, uniq, first = materialize_round(
+                    new_params, res, state)
+            info["count"] = snap.count
+            info["refinalize"] = mode if warm else None
+            info["snapshot_clock"] = snap.clock
+            out = (new_state, labels, info)
             served = self._make_served(out, res.centers[jnp.asarray(uniq)],
                                        first, int(len(uniq)), sketches,
                                        res.centers, res.labels, snap)
-            return out, served
-        new_params = self._average_params(res, params, aggregator,
-                                          snap.weights)
-        state = FederatedState(params=params, opt_state=None,
-                               n_clients=snap.count, step=0)
-        new_state, labels, info, uniq, first = materialize_round(
-            new_params, res, state)
-        info["count"] = snap.count
-        info["refinalize"] = mode if warm else None
-        info["snapshot_clock"] = snap.clock
-        out = (new_state, labels, info)
-        served = self._make_served(out, res.centers[jnp.asarray(uniq)],
-                                   first, int(len(uniq)), sketches,
-                                   res.centers, res.labels, snap)
         return out, served
 
     def _make_served(self, out, centers, first_idx, n_clusters, sketches,

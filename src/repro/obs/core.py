@@ -1,7 +1,7 @@
 """Dependency-free telemetry core: spans, counters, gauges, histograms.
 
-The observability spine of the engine (ISSUE 7).  Everything here is
-plain stdlib — no jax, no numpy — so the instrumented hot paths
+The observability spine of the engine.  Everything here is plain
+stdlib — no jax, no numpy — so the instrumented hot paths
 (``core/engine/session.py``, ``core/engine/aggregate.py``,
 ``core/federated_methods.py``) pay dict-update + ``perf_counter`` cost
 and nothing else, and the module is importable from anywhere without
@@ -15,6 +15,12 @@ cycles.
     (with ``parent``/``depth`` from the nesting stack) goes to every
     attached sink.  The yielded dict carries the measured ``ms`` after
     the block, so callers can reuse the number without re-timing.
+    Once JAX is imported, the span is also a
+    ``jax.profiler.TraceAnnotation`` of the same name: under a profiler
+    session it lands on the host planes of the trace, on the clock of
+    the device's events, so an idle gap on the device can be put down
+    to the span open on the host (about a microsecond when no profiler
+    runs).
   * sinks (``obs/sinks.py``) — anything with ``emit(event: dict)``;
     ``JsonlSink`` appends events as JSON lines, ``ConsoleSink`` prints
     a summary table on close, and ``Registry.snapshot()`` is the dict
@@ -29,9 +35,25 @@ from __future__ import annotations
 
 import contextlib
 import math
+import sys
 import threading
 import time
 from typing import Any, Callable, Iterable, Optional
+
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, imported on first use; a
+    null context while JAX is not imported (no profiler can run then),
+    so this module needs nothing beyond the standard library."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 class Histogram:
@@ -128,7 +150,8 @@ class Registry:
     @contextlib.contextmanager
     def span(self, name: str, **fields: Any):
         """Time a block: duration -> ``"<name>.ms"`` histogram + a
-        ``"span"`` event carrying nesting (``parent``/``depth``).  The
+        ``"span"`` event carrying nesting (``parent``/``depth``), and a
+        profiler trace annotation of the same name around it.  The
         yielded dict gains ``"ms"`` on exit."""
         stack = self._stack()
         info = {"name": name, **fields}
@@ -138,7 +161,8 @@ class Registry:
         stack.append(name)
         t0 = time.perf_counter()
         try:
-            yield info
+            with _annotation(name):
+                yield info
         finally:
             ms = (time.perf_counter() - t0) * 1e3
             stack.pop()
@@ -160,11 +184,14 @@ class Registry:
             if sink in self._sinks:
                 self._sinks.remove(sink)
 
-    def event(self, kind: str, **fields: Any) -> dict:
-        """Emit one structured event to every sink. Returns the event."""
-        evt = {"event": kind, "ts": time.time(), **fields}
+    def event(self, kind: str, **fields: Any) -> Optional[dict]:
+        """Emit one structured event to every sink. Returns the event,
+        or ``None`` when no sink is attached: then nothing is built."""
         with self._lock:
             sinks = list(self._sinks)
+        if not sinks:
+            return None
+        evt = {"event": kind, "ts": time.time(), **fields}
         for sink in sinks:
             sink.emit(evt)
         return evt
@@ -234,7 +261,7 @@ def observe(name: str, value: float) -> None:
     GLOBAL.observe(name, value)
 
 
-def event(kind: str, **fields: Any) -> dict:
+def event(kind: str, **fields: Any) -> Optional[dict]:
     return GLOBAL.event(kind, **fields)
 
 

@@ -14,7 +14,6 @@ from repro.roofline.engine_costs import (
     engine_kernel_report,
     hardware_info,
     kernel_probe,
-    program_rows_from_snapshot,
 )
 
 __all__ = [
@@ -30,6 +29,5 @@ __all__ = [
     "kernel_probe",
     "model_flops",
     "active_param_count",
-    "program_rows_from_snapshot",
     "roofline_terms",
 ]

@@ -1,21 +1,12 @@
 """Achieved-vs-peak roofline numbers for the aggregation engine.
 
 ``roofline/analysis.py`` predicts LM train/serve step times from a
-compiled dry run; this module closes the loop for the *engine* —
-the fused one-shot round, the session's split finalize, and the two
-engine kernels (``kmeans_assign``, ``group_ball_proj_batched``) — by
-pairing each program's XLA ``cost_analysis()`` (flops / bytes accessed)
-with its *measured* execute time:
-
-  * ``program_rows_from_snapshot(snapshot, hw)`` — reads the
-    ``"<label>.flops"`` / ``"<label>.bytes"`` gauges and
-    ``"<label>.execute.ms"`` histograms that ``engine.aggregate._Program``
-    records into ``repro.obs``, and turns every AOT program the run
-    compiled into an achieved-vs-peak row.  Free: the costs were
-    captured at the program's own compile, no second compile happens.
-  * ``kernel_probe`` / ``engine_kernel_report`` — standalone AOT
-    compile+time of the per-iteration kernels at a given problem size,
-    for the bench rows' ``kernels`` section.
+compiled dry run; this module closes the loop for the two engine
+kernels (``kmeans_assign``, ``group_ball_proj_batched``) by pairing a
+kernel's XLA ``cost_analysis()`` (flops / bytes accessed) with its
+*measured* execute time: ``kernel_probe`` / ``engine_kernel_report``
+are a standalone AOT compile+time of the per-iteration kernels at a
+given problem size, for the bench rows' ``kernels`` section.
 
 Peaks come from ``PEAKS``, keyed by the ``device_kind`` jax reports.
 A device missing from that table is an error, not a default: a CPU run
@@ -124,33 +115,6 @@ def engine_kernel_report(clients: int, sketch_dim: int, k: int,
         row["edges_capped"] = bool(capped)
         rows.append(row)
     return rows
-
-
-def program_rows_from_snapshot(snapshot: dict,
-                               hw: Hardware | None) -> dict:
-    """Achieved-vs-peak per AOT program, from an ``obs.snapshot()``.
-
-    Pairs every ``"<label>.flops"`` gauge with the matching
-    ``"<label>.execute.ms"`` histogram's p50 (warm-execution latency)
-    — the programs the run actually compiled and ran, at their real
-    shapes, with zero extra compiles.
-    """
-    gauges = snapshot.get("gauges", {})
-    hists = snapshot.get("histograms", {})
-    out = {}
-    for name, flops in gauges.items():
-        if not name.endswith(".flops"):
-            continue
-        label = name[:-len(".flops")]
-        h = hists.get(f"{label}.execute.ms")
-        if not h or not h.get("count"):
-            continue
-        cost = {"flops": flops,
-                "bytes accessed": gauges.get(f"{label}.bytes", 0.0)}
-        row = achieved_vs_peak(cost, h["p50"] / 1000.0, hw)
-        row["exec_count"] = h["count"]
-        out[label] = row
-    return out
 
 
 def hardware_info(hw: Hardware | None) -> dict:

@@ -56,6 +56,39 @@ def test_span_survives_exceptions_and_pops_stack():
     assert "parent" not in info              # stack was popped on the error
 
 
+def test_span_reaches_the_profiler_trace(tmp_path):
+    """A span opened under a profiler session lands on a host plane of
+    the trace, by name, beside the device's events."""
+    import jax
+
+    reg = Registry()
+    with jax.profiler.trace(str(tmp_path)):
+        with reg.span("obs.test.traced"):
+            jax.block_until_ready(jax.numpy.arange(8.0) * 2.0)
+    path, = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    names = {ev.name for plane in data.planes
+             if plane.name.startswith("/host")
+             for line in plane.lines for ev in line.events}
+    assert "obs.test.traced" in names
+    assert reg.histograms["obs.test.traced.ms"].count == 1
+
+
+def test_event_without_sinks_builds_nothing(monkeypatch):
+    """With no sink attached an event costs no clock read and no dict;
+    a span still records its histogram."""
+    reg = Registry()
+
+    def no_clock():
+        raise AssertionError("time.time() called with no sink attached")
+
+    monkeypatch.setattr(time, "time", no_clock)
+    assert reg.event("fed.round", round=0) is None
+    with reg.span("quiet"):
+        pass
+    assert reg.histograms["quiet.ms"].count == 1
+
+
 # -------------------------------------------------------------- histograms
 
 @pytest.mark.parametrize("n", [1, 2, 5, 17, 100])

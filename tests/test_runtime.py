@@ -16,12 +16,19 @@ from repro import runtime
 
 @pytest.fixture
 def clean_env(monkeypatch):
+    # the setters write os.environ directly, and monkeypatch restores
+    # only variables that existed when it touched them: put the whole
+    # environment back, or an unknown XLA flag written here is parsed
+    # (and refused) by the next backend this process loads
+    saved = dict(os.environ)
     for var in ("XLA_FLAGS", "JAX_PLATFORMS", "JAX_ENABLE_X64",
                 "REPRO_PLATFORM", "REPRO_X64", "REPRO_CPU_THREADS",
                 "REPRO_HOST_DEVICES", "REPRO_XLA_FLAGS",
                 "OMP_NUM_THREADS", "MKL_NUM_THREADS"):
         monkeypatch.delenv(var, raising=False)
-    return monkeypatch
+    yield monkeypatch
+    os.environ.clear()
+    os.environ.update(saved)
 
 
 def test_merge_xla_flags_dedupes_by_name_last_wins():

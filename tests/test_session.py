@@ -400,3 +400,72 @@ def test_session_drift_gauge_and_route_histogram():
     # a re-finalize re-anchors: the routed accumulator starts over
     sess.finalize(algorithm="kmeans-device", k=3)
     assert sess.drift is None
+
+
+def _ingest_case(kind):
+    """A session and the one wave under test: ``(session, ingest)``."""
+    pts, _ = make_blobs(4, [6, 6], 5)
+    if kind == "scatter":
+        # the first block ages out, so the wave refills evicted rows
+        # out of order and goes through the row-scatter program
+        sess = AggregationSession(12, sketch_dim=16, staleness="max_age=1")
+        for ids in (range(0, 4), range(4, 8), range(8, 10)):
+            sess.ingest({"theta": jnp.asarray(pts[list(ids)])},
+                        client_ids=ids)
+        return sess, lambda: sess.ingest(
+            {"theta": jnp.asarray(pts[:3])}, client_ids=["x", "y", "z"])
+    sess = AggregationSession(12, sketch_dim=16)
+    if kind == "sketches":
+        return sess, lambda: sess.ingest(
+            sketches=np.ones((4, 16), np.float32))
+    ids = range(4) if kind == "keyed" else None
+    return sess, lambda: sess.ingest({"theta": pts[:4]}, client_ids=ids)
+
+
+@pytest.mark.parametrize("kind", ["keyed", "anonymous", "scatter",
+                                  "sketches"])
+def test_ingest_wave_splits_into_transfer_and_program(kind):
+    """Every wave times its host-to-device transfer and its ingest
+    program as two children of ``session.ingest``, which encloses
+    both."""
+    from repro import obs
+
+    sess, ingest = _ingest_case(kind)
+    obs.reset()
+    sink = obs.add_sink(obs.ListSink())
+    try:
+        rows = ingest()
+    finally:
+        obs.remove_sink(sink)
+    if kind == "scatter":
+        assert list(rows) != sorted(rows)       # not one contiguous run
+    hists = obs.snapshot()["histograms"]
+    spans = {e["name"]: e for e in sink.events if e["event"] == "span"}
+    for child in ("session.ingest.transfer", "session.ingest.program"):
+        assert hists[f"{child}.ms"]["count"] == 1
+        assert spans[child]["parent"] == "session.ingest"
+    assert hists["session.ingest.ms"]["count"] == 1
+    assert (spans["session.ingest.transfer"]["ms"]
+            + spans["session.ingest.program"]["ms"]
+            <= spans["session.ingest"]["ms"])
+
+
+@pytest.mark.parametrize("mode", ["params", "sketches"])
+def test_finalize_and_refinalize_time_their_host_tail(mode):
+    """The round's host tail (label compaction, meta pull, drift
+    anchor) is one ``session.materialize`` span per round, cold and
+    warm, with or without parameters."""
+    from repro import obs
+
+    pts, _ = make_blobs(5, [7, 7], 5)
+    sess = AggregationSession(len(pts), sketch_dim=16, seed=0)
+    if mode == "params":
+        sess.ingest({"theta": jnp.asarray(pts)})
+    else:
+        sess.ingest(sketches=pts @ np.eye(5, 16, dtype=np.float32))
+    obs.reset()
+    sess.finalize(algorithm="kmeans-device", k=2)
+    sess.refinalize()
+    snap = obs.snapshot()["histograms"]
+    assert snap["session.materialize.ms"]["count"] == 2
+    assert snap["session.refinalize.ms"]["count"] == 1
