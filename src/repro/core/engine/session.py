@@ -19,7 +19,9 @@ this module is the stateful redesign:
     buffers both) instead of appended — ``count`` means live clients,
     not uploads.  Contiguous writes keep the ``dynamic_update_slice``
     fast path; keyed replacements and free-list reuse go through a
-    row-scatter program.
+    row-scatter program.  ``ingest`` returns once the wave is on the
+    device and its program is queued, so the next wave's transfer runs
+    under it; up to ``capacity`` rows of wave programs stay in flight.
   * staleness — the session advances a logical clock per wave and
     stamps every written row; a pluggable policy
     (``engine/staleness.py``: ``none`` | ``max_age`` sliding window |
@@ -58,6 +60,7 @@ graph — streams the same way.
 """
 from __future__ import annotations
 
+from collections import deque
 from typing import NamedTuple, Optional
 
 import jax
@@ -198,6 +201,9 @@ class AggregationSession:
         self._high = 0                 # high-water mark of ever-written rows
         self._count = 0                # LIVE clients (not uploads)
         self._clock = 0                # logical time, +1 per ingested wave
+        # wave programs queued and not yet known to have run: (handle,
+        # rows) in dispatch order, bounded to ``capacity`` rows
+        self._in_flight: deque = deque()
         # ---- finalize / serving state --------------------------------
         self._final = None             # round of the CURRENT buffer contents
         self._served: Optional[ServedRound] = None  # atomically-swapped
@@ -220,6 +226,9 @@ class AggregationSession:
                 sk = sketch_transform(sk, offset)
             return sk
 
+        # every ingest program also returns a small output that no later
+        # wave donates, the wave's sketch rows: the session's handle on
+        # whether the program has run (``_in_flight``)
         def _ingest(sk_buf, p_buf, wave, offset):
             sk = _sketch_wave(wave, offset)
             sk_buf = self._constrain(
@@ -228,7 +237,7 @@ class AggregationSession:
                 lambda b, w: self._constrain(
                     jax.lax.dynamic_update_slice_in_dim(b, w, offset, 0)),
                 p_buf, wave)
-            return sk_buf, p_buf
+            return sk_buf, p_buf, sk
 
         def _ingest_scatter(sk_buf, p_buf, wave, rows):
             sk = _sketch_wave(wave, rows[0])
@@ -236,18 +245,24 @@ class AggregationSession:
             p_buf = jax.tree_util.tree_map(
                 lambda b, w: self._constrain(b.at[rows].set(w)),
                 p_buf, wave)
-            return sk_buf, p_buf
+            return sk_buf, p_buf, sk
 
+        # the sketch-only handle is read back from the written buffer:
+        # without a transform the wave's rows are the program's input,
+        # which JAX would hand back as it is, ready before the write
         def _ingest_sk(sk_buf, sk, offset):
             if sketch_transform is not None:
                 sk = sketch_transform(sk, offset)
-            return self._constrain(
+            sk_buf = self._constrain(
                 jax.lax.dynamic_update_slice_in_dim(sk_buf, sk, offset, 0))
+            return sk_buf, jax.lax.dynamic_slice_in_dim(
+                sk_buf, offset, sk.shape[0], 0)
 
         def _ingest_sk_scatter(sk_buf, sk, rows):
             if sketch_transform is not None:
                 sk = sketch_transform(sk, rows[0])
-            return self._constrain(sk_buf.at[rows].set(sk))
+            sk_buf = self._constrain(sk_buf.at[rows].set(sk))
+            return sk_buf, sk_buf[rows]
 
         # donate the capacity-sized buffers so XLA updates them in place
         # (a fresh full-size copy per wave would defeat the streaming
@@ -427,6 +442,20 @@ class AggregationSession:
         new id takes a free (possibly previously evicted) row.  Returns
         the (w,) row assignment for keyed waves, the wave's offset for
         anonymous ones.
+
+        Returning acknowledges the wave: it is on the device and its
+        ingest program is queued, so its write is ordered before any
+        later ``snapshot()`` or read of the buffers (programs on a
+        device run in dispatch order, and the buffers are rebound to
+        the program's outputs).  The caller may reuse its host arrays.
+        The program itself may still be running; a failure of it
+        surfaces at the next wait on its outputs (a later wave's wait
+        on the in-flight window, a consumer of the next snapshot, or a
+        round program), not here.  Programs in flight are bounded to
+        ``capacity`` rows: a wave first waits for the oldest until it
+        fits, which the ``session.ingest.program`` span times, and the
+        ``session.ingest.in_flight`` histogram records the programs
+        still in flight, this one included, as it returns.
         """
         if (wave is None) == (sketches is None):
             raise ValueError("pass exactly one of wave= or sketches=")
@@ -453,23 +482,20 @@ class AggregationSession:
                 wave)
         offset = int(rows[0])
         contiguous = self._contiguous(rows)
-        with obs.span("session.ingest", wave=w, offset=offset,
-                      mode="params"):
+        ingest_fn = self._ingest_fn if contiguous else self._ingest_scatter_fn
+
+        def queue():
             # the program is queued before the wave lands, as a jitted
-            # call on host arrays queues it, so it starts on the transfer's
-            # heels: the transfer span ends when the wave is on the
-            # device, the program span when the buffers are written
-            with obs.span("session.ingest.transfer"):
-                wave, index = self._upload(
-                    (wave, np.int32(offset) if contiguous
-                     else np.asarray(rows, np.int32)))
-                ingest_fn = (self._ingest_fn if contiguous
-                             else self._ingest_scatter_fn)
-                self._sketches, self._params = ingest_fn(
-                    self._sketches, self._params, wave, index)
-                jax.block_until_ready((wave, index))
-            with obs.span("session.ingest.program"):
-                jax.block_until_ready(self._sketches)
+            # call on host arrays queues it, so it starts on the
+            # transfer's heels
+            uploaded = self._upload(
+                (wave, np.int32(offset) if contiguous
+                 else np.asarray(rows, np.int32)))
+            self._sketches, self._params, handle = ingest_fn(
+                self._sketches, self._params, *uploaded)
+            return uploaded, handle
+
+        self._write_wave(w, offset, "params", queue)
         obs.count("session.ingest.bytes",
                   sum(l.size * l.dtype.itemsize for l in leaves))
         self._commit_rows(rows, client_ids)
@@ -490,22 +516,51 @@ class AggregationSession:
         self._mode = "sketches"    # only after validation, as above
         offset = int(rows[0])
         contiguous = self._contiguous(rows)
-        with obs.span("session.ingest", wave=w, offset=offset,
-                      mode="sketches"):
-            with obs.span("session.ingest.transfer"):      # as above
-                sketches = jnp.asarray(sketches, jnp.float32)
-                index = self._upload(np.int32(offset) if contiguous
-                                     else np.asarray(rows, np.int32))
-                ingest_fn = (self._ingest_sk_fn if contiguous
-                             else self._ingest_sk_scatter_fn)
-                self._sketches = ingest_fn(self._sketches, sketches, index)
-                jax.block_until_ready((sketches, index))
-            with obs.span("session.ingest.program"):
-                jax.block_until_ready(self._sketches)
-        obs.count("session.ingest.bytes",
-                  sketches.size * sketches.dtype.itemsize)
+        ingest_fn = (self._ingest_sk_fn if contiguous
+                     else self._ingest_sk_scatter_fn)
+
+        def queue():                                      # as above
+            uploaded = (jnp.asarray(sketches, jnp.float32),
+                        self._upload(np.int32(offset) if contiguous
+                                     else np.asarray(rows, np.int32)))
+            self._sketches, handle = ingest_fn(self._sketches, *uploaded)
+            return uploaded, handle
+
+        self._write_wave(w, offset, "sketches", queue)
+        obs.count("session.ingest.bytes", w * self.sketch_dim * 4)
         self._commit_rows(rows, client_ids)
         return rows if client_ids is not None else offset
+
+    def _write_wave(self, w: int, offset: int, mode: str, queue) -> None:
+        """The timed part of a wave: wait until the programs in flight
+        leave room for ``w`` more rows, then ``queue()`` starts the
+        transfer, queues the ingest program and returns ``(uploaded
+        arrays, handle)``.  Returns once the wave has landed; the
+        program's handle joins ``_in_flight``."""
+        with obs.span("session.ingest", wave=w, offset=offset, mode=mode):
+            with obs.span("session.ingest.program"):
+                self._await_window(w)
+            with obs.span("session.ingest.transfer"):
+                uploaded, handle = queue()
+                jax.block_until_ready(uploaded)
+        self._in_flight.append((handle, w))
+        self._forget_finished()
+        obs.observe("session.ingest.in_flight", len(self._in_flight))
+
+    def _forget_finished(self) -> None:
+        self._in_flight = deque(e for e in self._in_flight
+                                if not e[0].is_ready())
+
+    def _await_window(self, w: int) -> None:
+        """Bound the wave programs in flight to ``capacity`` rows, one
+        full buffer of waves: block on the oldest while they and a wave
+        of ``w`` more rows would exceed it."""
+        self._forget_finished()
+        held = sum(n for _, n in self._in_flight)
+        while self._in_flight and held + w > self.capacity:
+            handle, n = self._in_flight.popleft()
+            handle.block_until_ready()
+            held -= n
 
     # --------------------------------------------------------- staleness
 

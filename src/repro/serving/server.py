@@ -203,7 +203,15 @@ class RouteServer:
     def ingest(self, wave=None, *, sketches=None, client_ids=None):
         """Thread-safe ingest; returns ``(rows_or_offset, clock)`` where
         ``clock`` is the session clock right after this wave's commit —
-        the replay key of the serialized-equivalence contract."""
+        the replay key of the serialized-equivalence contract.
+
+        The return acknowledges the wave as ``AggregationSession.ingest``
+        does: it is on the device and its write is ordered before every
+        later snapshot, while its ingest program may still run; a failed
+        program surfaces at the next wait on its outputs.  So the lock
+        is held for the wait on the in-flight window and the transfer
+        (the ``session.ingest.program`` and ``.transfer`` spans), not for
+        the program."""
         with self._ingest_lock:
             result = self.session.ingest(wave, sketches=sketches,
                                          client_ids=client_ids)

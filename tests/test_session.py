@@ -469,3 +469,123 @@ def test_finalize_and_refinalize_time_their_host_tail(mode):
     snap = obs.snapshot()["histograms"]
     assert snap["session.materialize.ms"]["count"] == 2
     assert snap["session.refinalize.ms"]["count"] == 1
+
+
+def _ordering_waves(kind, rng, n_waves=24, w=4, clients=12):
+    """``(capacity, waves)`` for the no-sync ordering test: each wave is
+    ``(values, client_ids)``, written back to back on one ingest path."""
+    cap = n_waves * w if kind == "anonymous" else clients
+    waves = []
+    for i in range(n_waves):
+        if kind == "anonymous":
+            ids = None
+        elif kind == "keyed":             # whole blocks: contiguous rows
+            lo = (i % (clients // w)) * w
+            ids = list(range(lo, lo + w))
+        else:                             # any clients: scattered rows
+            ids = [int(c) for c in rng.choice(clients, w, replace=False)]
+        waves.append((rng.normal(size=(w, 16)).astype(np.float32), ids))
+    return cap, waves
+
+
+@pytest.mark.parametrize("kind", ["keyed", "anonymous", "scatter",
+                                  "sketches"])
+def test_unsynced_waves_land_before_the_snapshot(kind):
+    """Waves ingested back to back, with no wait on their programs, are
+    all in the next snapshot: every live row holds its last write."""
+    cap, waves = _ordering_waves(kind, np.random.default_rng(5))
+    sess = AggregationSession(cap, sketch_dim=16, seed=3)
+    last = {}
+    for values, ids in waves:
+        if kind == "sketches":
+            rows = sess.ingest(sketches=values, client_ids=ids)
+        else:
+            rows = sess.ingest({"theta": values}, client_ids=ids)
+        if ids is None:
+            rows = range(rows, rows + len(values))
+        for row, v in zip(rows, values):
+            last[int(row)] = v
+    snap = sess.snapshot()
+    ref = np.stack([last[r] for r in sorted(last)])
+    assert snap.count == len(last)
+    if kind == "sketches":
+        np.testing.assert_array_equal(np.asarray(snap.sketches), ref)
+    else:
+        np.testing.assert_array_equal(np.asarray(snap.params["theta"]), ref)
+        np.testing.assert_allclose(
+            np.asarray(snap.sketches),
+            np.asarray(sess.sketch_params({"theta": jnp.asarray(ref)})),
+            rtol=1e-5, atol=1e-5)
+
+
+class _Handle:
+    """A wave program's handle whose readiness the test sets."""
+
+    def __init__(self):
+        self.ready = self.waited = False
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        self.waited = self.ready = True
+        return self
+
+
+@pytest.mark.parametrize("mode", ["params", "sketches"])
+def test_ingest_waits_only_when_capacity_rows_are_in_flight(mode):
+    """With ``capacity = 3w`` and programs that never finish on their
+    own, three waves go in without a wait, the fourth waits on the
+    oldest program, and a program that finishes makes room again; the
+    rows in flight never exceed ``capacity``."""
+    from repro import obs
+
+    w = 4
+    sess = AggregationSession(3 * w, sketch_dim=16)
+    attr = "_ingest_fn" if mode == "params" else "_ingest_sk_fn"
+    real, handles = getattr(sess, attr), []
+
+    def program(*args):
+        *bufs, _ = real(*args)
+        held = w * sum(not h.ready for h in handles)
+        assert held + w <= sess.capacity
+        handles.append(_Handle())
+        return (*bufs, handles[-1])
+
+    setattr(sess, attr, program)
+
+    def wave(block):
+        ids = range(block * w, block * w + w)
+        values = np.full((w, 16), float(block), np.float32)
+        if mode == "params":
+            return sess.ingest({"theta": values}, client_ids=ids)
+        return sess.ingest(sketches=values, client_ids=ids)
+
+    obs.reset()
+    for block in range(3):
+        wave(block)
+    assert not any(h.waited for h in handles)
+    wave(0)                                   # a fourth wave: waits
+    assert [h.waited for h in handles] == [True, False, False, False]
+    handles[1].ready = True                   # finishes on its own
+    wave(1)
+    assert not any(h.waited for h in handles[1:])
+    hist = obs.snapshot()["histograms"]["session.ingest.in_flight"]
+    assert hist["count"] == 5
+    assert (hist["min"], hist["max"], hist["sum"]) == (1, 3, 12)
+
+
+@pytest.mark.parametrize("kind", ["keyed", "anonymous", "scatter",
+                                  "sketches"])
+def test_ingest_observes_the_programs_in_flight_once_per_wave(kind):
+    """``session.ingest.in_flight`` takes one value a wave: the session's
+    wave programs still in flight as it returns, this one included."""
+    from repro import obs
+
+    sess, ingest = _ingest_case(kind)
+    obs.reset()
+    ingest()
+    ingest()
+    hist = obs.snapshot()["histograms"]["session.ingest.in_flight"]
+    assert hist["count"] == 2
+    assert 0 <= hist["min"] <= hist["max"] <= sess.clock
