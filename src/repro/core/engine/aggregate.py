@@ -305,17 +305,28 @@ def compact_labels(raw_labels):
     return labels.astype(np.int32), uniq, first
 
 
+@functools.lru_cache(maxsize=8)
+def _opt_init_program(sharding):
+    """Per-client AdamW state laid out by ``sharding``, the per-client
+    parameters' own: under a mesh its client axis is sharded as theirs
+    is, where zeros made outside such a program would all land on one
+    device."""
+    return jax.jit(jax.vmap(adamw_init), out_shardings=sharding)
+
+
 def materialize_round(new_params, res, state: FederatedState):
     """Host materialization of a device round: compacted labels + scalar
-    meta are the ONLY transfers; params/opt state stay device pytrees.
+    meta are the ONLY transfers; params/opt state stay device pytrees,
+    the opt state laid out as the params are.
     Returns ``(new_state, labels, info, uniq, first)`` — ``uniq`` the raw
     ids behind each compact label, ``first`` one member index per compact
     id (the session's routing/serving handles)."""
     labels, uniq, first = compact_labels(res.labels)
     meta = meta_to_host(res.meta)
+    sharding = jax.tree_util.tree_leaves(new_params)[0].sharding
     new_state = FederatedState(
         params=new_params,
-        opt_state=jax.vmap(adamw_init)(new_params),
+        opt_state=_opt_init_program(sharding)(new_params),
         n_clients=state.n_clients, step=state.step)
     info = {"n_clusters": int(len(uniq)), "meta": meta, "engine": "device"}
     return new_state, labels, info, uniq, first

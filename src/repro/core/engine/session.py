@@ -18,10 +18,11 @@ this module is the stateful redesign:
     returning client's row is replaced in place (sketch and params
     buffers both) instead of appended — ``count`` means live clients,
     not uploads.  Contiguous writes keep the ``dynamic_update_slice``
-    fast path; keyed replacements and free-list reuse go through a
-    row-scatter program.  ``ingest`` returns once the wave is on the
-    device and its program is queued, so the next wave's transfer runs
-    under it; up to ``capacity`` rows of wave programs stay in flight.
+    fast path; keyed replacements, free-list reuse and every wave into
+    buffers sharded over a mesh go through a row-scatter program.
+    ``ingest`` returns once the wave is on the device and its program
+    is queued, so the next wave's transfer runs under it; up to
+    ``capacity`` rows of wave programs stay in flight.
   * staleness — the session advances a logical clock per wave and
     stamps every written row; a pluggable policy
     (``engine/staleness.py``: ``none`` | ``max_age`` sliding window |
@@ -302,16 +303,28 @@ class AggregationSession:
         mesh's first device: they hold Pallas kernels, which XLA cannot
         partition across devices.  What moves there is a (rows,
         sketch_dim) matrix, small next to the sharded parameter
-        buffer."""
+        buffer; the ``session.mesh.bytes`` counter adds the bytes that
+        were not on that device yet."""
         if self.mesh is None:
             return sketches
-        return jax.device_put(sketches, self.mesh.devices.flat[0])
+        first = self.mesh.devices.flat[0]
+        obs.count("session.mesh.bytes", sketches.nbytes - sum(
+            s.data.nbytes for s in sketches.addressable_shards
+            if s.device == first))
+        return jax.device_put(sketches, first)
 
     def _replicate(self, x):
         """Under a mesh, copy a clustering output to every device so the
-        sharded parameter reduction can read it."""
+        sharded parameter reduction can read it; the
+        ``session.mesh.bytes`` counter adds the bytes of the copies that
+        did not exist yet."""
         if self.mesh is None:
             return x
+        n = self.mesh.devices.size
+        obs.count("session.mesh.bytes", sum(
+            leaf.nbytes * n - sum(s.data.nbytes
+                                  for s in leaf.addressable_shards)
+            for leaf in jax.tree_util.tree_leaves(x)))
         return jax.device_put(x, NamedSharding(self.mesh, P()))
 
     @property
@@ -423,9 +436,13 @@ class AggregationSession:
         self._final = None             # buffer contents left the round
         self.evict_stale()
 
-    @staticmethod
-    def _contiguous(rows: np.ndarray) -> bool:
-        return bool(np.array_equal(
+    def _contiguous(self, rows: np.ndarray) -> bool:
+        """Whether a wave takes the ``dynamic_update_slice`` programs:
+        its rows are one run, and the buffers are not sharded.  Under a
+        mesh XLA partitions a dynamic slice of the sharded client axis
+        by gathering the whole buffer onto every device, where the
+        row-scatter program writes each shard in place."""
+        return self.mesh is None and bool(np.array_equal(
             rows, np.arange(rows[0], rows[0] + len(rows))))
 
     def ingest(self, wave=None, *, sketches=None, client_ids=None):
@@ -496,8 +513,7 @@ class AggregationSession:
             return uploaded, handle
 
         self._write_wave(w, offset, "params", queue)
-        obs.count("session.ingest.bytes",
-                  sum(l.size * l.dtype.itemsize for l in leaves))
+        self._count_wave(sum(l.size * l.dtype.itemsize for l in leaves))
         self._commit_rows(rows, client_ids)
         return rows if client_ids is not None else offset
 
@@ -520,16 +536,25 @@ class AggregationSession:
                      else self._ingest_sk_scatter_fn)
 
         def queue():                                      # as above
-            uploaded = (jnp.asarray(sketches, jnp.float32),
-                        self._upload(np.int32(offset) if contiguous
-                                     else np.asarray(rows, np.int32)))
+            uploaded = self._upload(
+                (jnp.asarray(sketches, jnp.float32),
+                 np.int32(offset) if contiguous
+                 else np.asarray(rows, np.int32)))
             self._sketches, handle = ingest_fn(self._sketches, *uploaded)
             return uploaded, handle
 
         self._write_wave(w, offset, "sketches", queue)
-        obs.count("session.ingest.bytes", w * self.sketch_dim * 4)
+        self._count_wave(w * self.sketch_dim * 4)
         self._commit_rows(rows, client_ids)
         return rows if client_ids is not None else offset
+
+    def _count_wave(self, nbytes: int) -> None:
+        """A wave's bytes, and under a mesh the bytes its transfer
+        placed on the devices: ``_upload`` copies it to each of them."""
+        obs.count("session.ingest.bytes", nbytes)
+        if self.mesh is not None:
+            obs.count("session.ingest.device_bytes",
+                      nbytes * self.mesh.devices.size)
 
     def _write_wave(self, w: int, offset: int, mode: str, queue) -> None:
         """The timed part of a wave: wait until the programs in flight

@@ -589,3 +589,182 @@ def test_ingest_observes_the_programs_in_flight_once_per_wave(kind):
     hist = obs.snapshot()["histograms"]["session.ingest.in_flight"]
     assert hist["count"] == 2
     assert 0 <= hist["min"] <= hist["max"] <= sess.clock
+
+
+# ------------------------------------------------ four-device mesh sessions
+
+def _on_four_devices(code: str) -> dict:
+    """Run ``code`` in a fresh interpreter on four virtual CPU devices
+    (the device count is fixed when JAX starts, so not in this process)
+    and return the JSON object it prints last."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_session_serves_the_last_uploads_of_back_to_back_keyed_waves():
+    """Keyed waves sent back to back, with no wait on their programs, to
+    a session sharded over four devices: runs that straddle the shards,
+    then re-uploads of scattered clients.  The served models are the
+    float64 per-cluster means of every row's last upload, the partition
+    is the planted one, and both equal what a session without a mesh
+    serves; the opt state stays sharded as the params are."""
+    out = _on_four_devices("""
+        import json
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+        from repro.core.engine import AggregationSession
+
+        rng = np.random.default_rng(11)
+        clients, k, d = 48, 3, 12
+        truth = rng.permutation(np.arange(clients) % k)
+        centres = rng.normal(size=(k, d)) * 20
+        draws = [(centres[truth] + rng.normal(size=(clients, d)))
+                 .astype(np.float32) for _ in range(2)]
+        waves, lo = [], 0
+        for w in [5, 7] * 4:
+            waves.append(list(range(lo, lo + w)))
+            lo += w
+        waves += [[int(c) for c in rng.choice(clients, 8, replace=False)]
+                  for _ in range(4)]
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        out = {}
+        for name, m in (("mesh", mesh), ("plain", None)):
+            sess = AggregationSession(clients, sketch_dim=16, seed=2,
+                                      mesh=m)
+            last = np.zeros((clients, d), np.float64)
+            for i, ids in enumerate(waves):
+                values = draws[i >= 8][ids]
+                rows = sess.ingest({"theta": values}, client_ids=ids)
+                last[rows] = values
+            state, labels, _ = sess.finalize(algorithm="kmeans-device", k=k)
+            out[name] = (state, np.asarray(labels), last)
+        state, labels, last = out["mesh"]
+        means = np.stack([last[truth == c].mean(0) for c in range(k)])
+        theta = np.asarray(state.params["theta"])
+        plain, plain_labels, _ = out["plain"]
+        print(json.dumps({
+            "pairs": [[int(a), int(b)] for a, b in zip(labels, truth)],
+            "model_err": float(np.abs(theta - means[truth]).max()),
+            "labels_as_plain": bool((labels == plain_labels).all()),
+            "gap_to_plain": float(np.abs(
+                theta - np.asarray(plain.params["theta"])).max()),
+            "specs": [str(state.params["theta"].sharding.spec),
+                      str(state.opt_state["mu"]["theta"].sharding.spec),
+                      str(state.opt_state["nu"]["theta"].sharding.spec)]}))
+    """)
+    labels, truth = np.array(out["pairs"]).T
+    assert same_partition(labels, truth)
+    assert out["model_err"] < 1e-4
+    assert out["labels_as_plain"]
+    assert out["gap_to_plain"] < 1e-5
+    assert out["specs"] == ["PartitionSpec('data',)"] * 3
+
+
+def test_mesh_session_counts_the_bytes_it_places_and_moves():
+    """Under a mesh, ``session.ingest.device_bytes`` is each wave's bytes
+    times the four devices it is copied to, and ``session.mesh.bytes``
+    is what a round moves between them: the three shards of the sketch
+    rows not on the first device, and the labels and centres copied
+    from it to the other three."""
+    out = _on_four_devices("""
+        import json
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+        from repro import obs
+        from repro.core.engine import AggregationSession
+
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        sess = AggregationSession(32, sketch_dim=16, mesh=mesh)
+        rng = np.random.default_rng(0)
+        obs.reset()
+        for lo in (0, 16):
+            sess.ingest({"theta": rng.normal(size=(16, 10))
+                         .astype(np.float32)},
+                        client_ids=range(lo, lo + 16))
+        waves = obs.snapshot()["counters"]
+        sess.finalize(algorithm="kmeans-device", k=2)
+        print(json.dumps({"waves": waves,
+                          "after": obs.snapshot()["counters"]}))
+    """)
+    wave = 16 * 10 * 4
+    assert out["waves"]["session.ingest.bytes"] == 2 * wave
+    assert out["waves"]["session.ingest.device_bytes"] == 2 * wave * 4
+    assert "session.mesh.bytes" not in out["waves"]
+    sketches, labels, centres = 32 * 16 * 4, 32 * 4, 2 * 16 * 4
+    assert out["after"]["session.mesh.bytes"] == (
+        sketches * 3 // 4 + (labels + centres) * 3)
+
+
+def test_a_session_without_a_mesh_records_no_mesh_counter():
+    from repro import obs
+
+    pts, _ = make_blobs(7, [8, 8], 6)
+    sess = AggregationSession(len(pts), sketch_dim=16)
+    obs.reset()
+    sess.ingest({"theta": jnp.asarray(pts)}, client_ids=range(len(pts)))
+    sess.finalize(algorithm="kmeans-device", k=2)
+    counters = obs.snapshot()["counters"]
+    assert counters["session.ingest.bytes"] == pts.nbytes
+    assert "session.ingest.device_bytes" not in counters
+    assert "session.mesh.bytes" not in counters
+
+
+def test_mesh_session_writes_waves_without_gathering_the_buffers():
+    """Every wave program a session sharded over four devices runs
+    (a keyed run of rows, an anonymous wave, scattered keyed rows, a
+    sketch wave) writes each shard in place: none gathers a buffer
+    across the devices, as XLA does to partition a dynamic slice of the
+    sharded client axis."""
+    out = _on_four_devices("""
+        import json
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+        from repro.core.engine import AggregationSession
+
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        ran = []
+
+        def recorded(sess, attr):
+            fn = getattr(sess, attr)
+
+            def program(*args):
+                ran.append((attr, fn, args))
+                return fn(*args)
+
+            setattr(sess, attr, program)
+
+        programs = ("_ingest_fn", "_ingest_scatter_fn", "_ingest_sk_fn",
+                    "_ingest_sk_scatter_fn")
+        params = AggregationSession(32, sketch_dim=16, mesh=mesh)
+        sketches = AggregationSession(32, sketch_dim=16, mesh=mesh)
+        for sess in (params, sketches):
+            for attr in programs:
+                recorded(sess, attr)
+        rng = np.random.default_rng(0)
+        values = lambda w, d: rng.normal(size=(w, d)).astype(np.float32)
+        params.ingest({"theta": values(8, 10)}, client_ids=range(8))
+        params.ingest({"theta": values(8, 10)})
+        params.ingest({"theta": values(6, 10)}, client_ids=[7, 2, 5, 0, 3, 1])
+        sketches.ingest(sketches=values(8, 16), client_ids=range(8))
+        sketches.ingest(sketches=values(4, 16), client_ids=[6, 1, 4, 3])
+        print(json.dumps([[attr, fn.lower(*args).compile().as_text()
+                           .count("all-gather")]
+                          for attr, fn, args in ran]))
+    """)
+    assert len(out) == 5
+    assert all(gathers == 0 for _, gathers in out), out
