@@ -168,7 +168,8 @@ class AggregationSession:
         wave's first target row.
       staleness: a policy instance from ``engine/staleness.py`` or a
         spec string (``"none"`` | ``"max_age=3"`` | ``"exp_decay=2.0"``).
-      mesh / client_axis: shard the client axis of the buffers.
+      mesh / client_axis: shard the client axis of the buffers, and of
+        each upload wave whose rows the axis divides.
     """
 
     def __init__(self, capacity: int, *, sketch_dim: int = 256, cfg=None,
@@ -289,14 +290,23 @@ class AggregationSession:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, P(self.client_axis)))
 
-    def _upload(self, tree):
-        """Start the host-to-device transfer of an upload (wave leaves,
-        row index): to the default device, uncommitted, where the jitted
-        ingest takes host arrays; under a mesh replicated over it, which
-        is the input sharding the compiled ingest reads, so no reshard
-        follows the transfer."""
-        target = None if self.mesh is None else NamedSharding(self.mesh, P())
-        return jax.device_put(tree, target)
+    def _upload(self, wave, index):
+        """Start the host-to-device transfer of an upload: the wave (its
+        leaves, or its sketch rows) and its row index.  With no mesh both
+        go to the default device, uncommitted, where the jitted ingest
+        takes host arrays.  Under a mesh the index is copied to every
+        device, and a wave whose leading (wave) axis the client axis
+        divides is sharded over it: each device receives its share of
+        the rows over its own link, and the ingest program gathers the
+        rest over the interconnect.  Any other wave is copied whole to
+        every device."""
+        if self.mesh is None:
+            return jax.device_put((wave, index), None)
+        w = jax.tree_util.tree_leaves(wave)[0].shape[0]
+        split = w % self.mesh.shape[self.client_axis] == 0
+        return jax.device_put((wave, index), (
+            NamedSharding(self.mesh, P(self.client_axis) if split else P()),
+            NamedSharding(self.mesh, P())))
 
     def _to_cluster_device(self, sketches):
         """Under a mesh, the clustering and route programs run on the
@@ -506,14 +516,15 @@ class AggregationSession:
             # call on host arrays queues it, so it starts on the
             # transfer's heels
             uploaded = self._upload(
-                (wave, np.int32(offset) if contiguous
-                 else np.asarray(rows, np.int32)))
+                wave, np.int32(offset) if contiguous
+                else np.asarray(rows, np.int32))
             self._sketches, self._params, handle = ingest_fn(
                 self._sketches, self._params, *uploaded)
             return uploaded, handle
 
-        self._write_wave(w, offset, "params", queue)
-        self._count_wave(sum(l.size * l.dtype.itemsize for l in leaves))
+        placed = self._write_wave(w, offset, "params", queue)
+        self._count_wave(sum(l.size * l.dtype.itemsize for l in leaves),
+                         placed)
         self._commit_rows(rows, client_ids)
         return rows if client_ids is not None else offset
 
@@ -535,33 +546,43 @@ class AggregationSession:
         ingest_fn = (self._ingest_sk_fn if contiguous
                      else self._ingest_sk_scatter_fn)
 
+        # host rows stay on the host until ``_upload`` places them, so
+        # under a mesh each device's share crosses its own link
+        sketches = (sketches.astype(jnp.float32)
+                    if isinstance(sketches, jax.Array)
+                    else np.asarray(sketches, np.float32))
+
         def queue():                                      # as above
             uploaded = self._upload(
-                (jnp.asarray(sketches, jnp.float32),
-                 np.int32(offset) if contiguous
-                 else np.asarray(rows, np.int32)))
+                sketches,
+                np.int32(offset) if contiguous
+                else np.asarray(rows, np.int32))
             self._sketches, handle = ingest_fn(self._sketches, *uploaded)
             return uploaded, handle
 
-        self._write_wave(w, offset, "sketches", queue)
-        self._count_wave(w * self.sketch_dim * 4)
+        placed = self._write_wave(w, offset, "sketches", queue)
+        self._count_wave(w * self.sketch_dim * 4, placed)
         self._commit_rows(rows, client_ids)
         return rows if client_ids is not None else offset
 
-    def _count_wave(self, nbytes: int) -> None:
-        """A wave's bytes, and under a mesh the bytes its transfer
-        placed on the devices: ``_upload`` copies it to each of them."""
+    def _count_wave(self, nbytes: int, placed) -> None:
+        """A wave's bytes, and under a mesh the bytes its transfer placed
+        on the devices, summed over the shards of the uploaded wave
+        ``placed``: the wave's bytes once where ``_upload`` shards it
+        over the devices, its bytes times the devices where it copies
+        the wave whole to each."""
         obs.count("session.ingest.bytes", nbytes)
         if self.mesh is not None:
-            obs.count("session.ingest.device_bytes",
-                      nbytes * self.mesh.devices.size)
+            obs.count("session.ingest.device_bytes", sum(
+                s.data.nbytes for leaf in jax.tree_util.tree_leaves(placed)
+                for s in leaf.addressable_shards))
 
-    def _write_wave(self, w: int, offset: int, mode: str, queue) -> None:
+    def _write_wave(self, w: int, offset: int, mode: str, queue):
         """The timed part of a wave: wait until the programs in flight
         leave room for ``w`` more rows, then ``queue()`` starts the
         transfer, queues the ingest program and returns ``(uploaded
-        arrays, handle)``.  Returns once the wave has landed; the
-        program's handle joins ``_in_flight``."""
+        arrays, handle)``.  Returns the uploaded wave once every shard
+        of it has landed; the program's handle joins ``_in_flight``."""
         with obs.span("session.ingest", wave=w, offset=offset, mode=mode):
             with obs.span("session.ingest.program"):
                 self._await_window(w)
@@ -571,6 +592,7 @@ class AggregationSession:
         self._in_flight.append((handle, w))
         self._forget_finished()
         obs.observe("session.ingest.in_flight", len(self._in_flight))
+        return uploaded[0]
 
     def _forget_finished(self) -> None:
         self._in_flight = deque(e for e in self._in_flight

@@ -17,7 +17,7 @@ from repro.core.engine import AggregationSession
 from repro.core.federated import FederatedState, one_shot_aggregate
 from repro.optim import adamw_init
 
-from conftest import same_partition
+from conftest import gathered_rows, same_partition
 
 
 def make_blobs(seed, sizes, d, sep=25.0, noise=0.25):
@@ -673,12 +673,65 @@ def test_mesh_session_serves_the_last_uploads_of_back_to_back_keyed_waves():
     assert out["specs"] == ["PartitionSpec('data',)"] * 3
 
 
-def test_mesh_session_counts_the_bytes_it_places_and_moves():
-    """Under a mesh, ``session.ingest.device_bytes`` is each wave's bytes
-    times the four devices it is copied to, and ``session.mesh.bytes``
-    is what a round moves between them: the three shards of the sketch
-    rows not on the first device, and the labels and centres copied
-    from it to the other three."""
+def test_mesh_session_shards_a_wave_the_devices_divide_and_copies_others():
+    """A parameter or sketch wave whose rows the four devices divide
+    lands as one shard of a quarter of its rows on each device; a wave
+    of 6 rows lands whole on each.  The row index lands whole on each."""
+    out = _on_four_devices("""
+        import json
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+        from repro.core.engine import AggregationSession
+
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        placed = []
+
+        def recorded(sess):
+            upload = sess._upload
+
+            def record(wave, index):
+                out = upload(wave, index)
+                placed.append([
+                    [[s.device.id, list(s.data.shape)]
+                     for s in leaf.addressable_shards]
+                    for leaf in jax.tree_util.tree_leaves(out)])
+                return out
+
+            sess._upload = record
+            return sess
+
+        rng = np.random.default_rng(0)
+        values = lambda *shape: rng.normal(size=shape).astype(np.float32)
+        params = recorded(AggregationSession(32, sketch_dim=16, mesh=mesh))
+        params.ingest({"b": values(8, 3), "w": values(8, 5, 2)},
+                      client_ids=range(8))
+        params.ingest({"b": values(6, 3), "w": values(6, 5, 2)},
+                      client_ids=range(8, 14))
+        sketches = recorded(AggregationSession(32, sketch_dim=16, mesh=mesh))
+        sketches.ingest(sketches=values(12, 16), client_ids=range(12))
+        sketches.ingest(sketches=values(6, 16), client_ids=range(12, 18))
+        print(json.dumps(placed))
+    """)
+    devices = [0, 1, 2, 3]
+
+    def on_each(shape):
+        return [[d, list(shape)] for d in devices]
+
+    assert out == [
+        [on_each((2, 3)), on_each((2, 5, 2)), on_each((8,))],
+        [on_each((6, 3)), on_each((6, 5, 2)), on_each((6,))],
+        [on_each((3, 16)), on_each((12,))],
+        [on_each((6, 16)), on_each((6,))],
+    ]
+
+
+def test_mesh_session_serves_the_last_uploads_of_sharded_keyed_waves():
+    """Keyed waves the four devices divide, each sharded over them and
+    sent back to back: runs of rows that straddle the shards, then
+    re-uploads of scattered clients.  The served models are the float64
+    per-cluster means of every row's last upload, and the partition is
+    the planted one."""
     out = _on_four_devices("""
         import json
         import jax
@@ -687,26 +740,81 @@ def test_mesh_session_counts_the_bytes_it_places_and_moves():
         from repro import obs
         from repro.core.engine import AggregationSession
 
+        rng = np.random.default_rng(12)
+        clients, k, d = 48, 3, 12
+        truth = rng.permutation(np.arange(clients) % k)
+        centres = rng.normal(size=(k, d)) * 20
+        draws = [(centres[truth] + rng.normal(size=(clients, d)))
+                 .astype(np.float32) for _ in range(2)]
+        waves, lo = [], 0
+        for w in [4, 12, 8, 4, 12, 8]:
+            waves.append(list(range(lo, lo + w)))
+            lo += w
+        waves += [[int(c) for c in rng.choice(clients, 8, replace=False)]
+                  for _ in range(4)]
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        sess = AggregationSession(clients, sketch_dim=16, seed=2, mesh=mesh)
+        last = np.zeros((clients, d), np.float64)
+        obs.reset()
+        for i, ids in enumerate(waves):
+            values = draws[i >= 6][ids]
+            rows = sess.ingest({"theta": values}, client_ids=ids)
+            last[rows] = values
+        counters = obs.snapshot()["counters"]
+        state, labels, _ = sess.finalize(algorithm="kmeans-device", k=k)
+        means = np.stack([last[truth == c].mean(0) for c in range(k)])
+        theta = np.asarray(state.params["theta"])
+        print(json.dumps({
+            "pairs": [[int(a), int(b)] for a, b in zip(labels, truth)],
+            "model_err": float(np.abs(theta - means[truth]).max()),
+            "placed_per_uploaded": counters["session.ingest.device_bytes"]
+                                   / counters["session.ingest.bytes"]}))
+    """)
+    labels, truth = np.array(out["pairs"]).T
+    assert same_partition(labels, truth)
+    assert out["model_err"] < 1e-4
+    assert out["placed_per_uploaded"] == 1.0
+
+
+@pytest.mark.parametrize("w,copies,moved", [(16, 1, 3 / 4), (6, 4, 0)])
+def test_mesh_session_counts_the_bytes_it_places_and_moves(w, copies, moved):
+    """Under a mesh, ``session.ingest.device_bytes`` is what the transfer
+    placed: each wave's bytes once where the four devices divide its
+    rows and it is sharded over them, its bytes times four where they do
+    not and it is copied whole to each.  ``session.mesh.bytes`` is what
+    a round moves between them: the sketch rows not on the first device
+    yet (three of four shards of 32 live rows; none of 12, whose slice
+    of the buffer every device holds whole), and the labels and centres
+    copied from it to the other three."""
+    out = _on_four_devices("""
+        import json
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+        from repro import obs
+        from repro.core.engine import AggregationSession
+
+        w = %d
         mesh = Mesh(np.array(jax.devices()), ("data",))
         sess = AggregationSession(32, sketch_dim=16, mesh=mesh)
         rng = np.random.default_rng(0)
         obs.reset()
-        for lo in (0, 16):
-            sess.ingest({"theta": rng.normal(size=(16, 10))
+        for lo in (0, w):
+            sess.ingest({"theta": rng.normal(size=(w, 10))
                          .astype(np.float32)},
-                        client_ids=range(lo, lo + 16))
+                        client_ids=range(lo, lo + w))
         waves = obs.snapshot()["counters"]
         sess.finalize(algorithm="kmeans-device", k=2)
         print(json.dumps({"waves": waves,
                           "after": obs.snapshot()["counters"]}))
-    """)
-    wave = 16 * 10 * 4
+    """ % w)
+    wave = w * 10 * 4
     assert out["waves"]["session.ingest.bytes"] == 2 * wave
-    assert out["waves"]["session.ingest.device_bytes"] == 2 * wave * 4
+    assert out["waves"]["session.ingest.device_bytes"] == 2 * wave * copies
     assert "session.mesh.bytes" not in out["waves"]
-    sketches, labels, centres = 32 * 16 * 4, 32 * 4, 2 * 16 * 4
+    sketches, labels, centres = 2 * w * 16 * 4, 2 * w * 4, 2 * 16 * 4
     assert out["after"]["session.mesh.bytes"] == (
-        sketches * 3 // 4 + (labels + centres) * 3)
+        sketches * moved + (labels + centres) * 3)
 
 
 def test_a_session_without_a_mesh_records_no_mesh_counter():
@@ -728,7 +836,9 @@ def test_mesh_session_writes_waves_without_gathering_the_buffers():
     (a keyed run of rows, an anonymous wave, scattered keyed rows, a
     sketch wave) writes each shard in place: none gathers a buffer
     across the devices, as XLA does to partition a dynamic slice of the
-    sharded client axis."""
+    sharded client axis.  What a program gathers is the wave's own rows,
+    at most: a wave sharded over the devices reaches the shards that own
+    its rows that way."""
     out = _on_four_devices("""
         import json
         import jax
@@ -762,9 +872,14 @@ def test_mesh_session_writes_waves_without_gathering_the_buffers():
         params.ingest({"theta": values(6, 10)}, client_ids=[7, 2, 5, 0, 3, 1])
         sketches.ingest(sketches=values(8, 16), client_ids=range(8))
         sketches.ingest(sketches=values(4, 16), client_ids=[6, 1, 4, 3])
-        print(json.dumps([[attr, fn.lower(*args).compile().as_text()
-                           .count("all-gather")]
+        print(json.dumps([[attr, len(args[-1]),
+                           fn.lower(*args).compile().as_text()]
                           for attr, fn, args in ran]))
     """)
-    assert len(out) == 5
-    assert all(gathers == 0 for _, gathers in out), out
+    assert [attr for attr, _, _ in out] == [
+        "_ingest_scatter_fn"] * 3 + ["_ingest_sk_scatter_fn"] * 2
+    for attr, w, hlo in out:
+        rows = gathered_rows(hlo, [(10,), (16,)])
+        assert 32 not in rows, (attr, rows)
+        assert all(r <= w for r in rows), (attr, w, rows)
+        assert (w in rows) == (w % 4 == 0), (attr, w, rows)

@@ -1,11 +1,13 @@
-"""AOT compiles of the server's Pallas kernels for a described TPU v5e.
+"""AOT compiles of the server's Pallas kernels for a described TPU v5e,
+and of the wave program of a session sharded over a described 2x2 mesh.
 
 The kernels run on the chip only; interpret mode on CPU cannot see what
 the TPU compiler refuses (block shapes that do not match the HBM tiling,
 the (8, 128) block rule).  The TPU compiler is installed with jax and
 compiles for a chip that is described, not attached, so these tests
 compile each kernel at the shapes of the server's main path and check
-that the program holds the compiled kernel.
+that the program holds the compiled kernel.  How the TPU compiler
+partitions a program over a mesh shows in the collectives it adds.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and each
@@ -15,9 +17,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from conftest import gathered_rows
+from repro.core.engine import AggregationSession
 from repro.kernels.group_prox import (
     group_ball_proj_batched_pallas,
     group_ball_proj_pallas,
@@ -94,3 +100,32 @@ def test_group_ball_proj_batched_compiles_for_v5e(one_chip, b):
     # the AMA dual over C=16384 x knn_k=8 edge slots, L lambda rungs
     assert PALLAS_CALL in _compile(group_ball_proj_batched_pallas, one_chip,
                                    (b, 131072, 32), (b, 131072))
+
+
+def test_mesh_wave_program_gathers_only_the_wave_for_v5e(topo):
+    """The row-scatter program of a session whose buffers are sharded
+    over the described 2x2 mesh, fed a wave sharded over it as
+    ``AggregationSession._upload`` places one: the rows reach the shards
+    that own them by a gather of the wave, and no all-gather or
+    all-to-all holds as many rows as the buffers."""
+    capacity, w, sketch_dim = 96, 16, 32
+    row_shapes = {"W1": (24, 8), "b1": (8,), "W2": (8, 3), "b2": (3,)}
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    # buffers cannot be made on a described chip: the session is built
+    # without a mesh and given one before its program is traced, which
+    # is when the program reads it
+    sess = AggregationSession(capacity, sketch_dim=sketch_dim)
+    sess.mesh = mesh
+
+    def placed(shape, *spec, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    hlo = sess._ingest_scatter_fn.lower(
+        placed((capacity, sketch_dim), "data"),
+        {k: placed((capacity,) + r, "data") for k, r in row_shapes.items()},
+        {k: placed((w,) + r, "data") for k, r in row_shapes.items()},
+        placed((w,), dtype=jnp.int32)).compile().as_text()
+    rows = gathered_rows(hlo, [*row_shapes.values(), (sketch_dim,)])
+    assert w in rows, rows
+    assert capacity not in rows and max(rows) <= w, rows
